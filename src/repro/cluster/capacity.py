@@ -268,7 +268,7 @@ def plan_capacity(configs: Sequence[ClusterConfig],
                   slo_us: float = DEFAULT_SLO_US,
                   rate_ladder: Optional[Sequence[float]] = None,
                   degraded: bool = True, down_server: int = 0,
-                  sweeps: int = 512, fixpoint: str = "loop",
+                  sweeps: int = 512, fixpoint: str = "auto",
                   scan_backend: str = "auto",
                   max_refine: Optional[int] = None,
                   warm_ladder: bool = False) -> CapacityReport:

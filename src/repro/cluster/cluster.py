@@ -86,7 +86,7 @@ class Cluster:
 
     def compile(self, workload: ClusterWorkload, *,
                 down: Optional[int] = None, sweeps: int = 512,
-                fixpoint: str = "loop", scan_backend: str = "auto",
+                fixpoint: str = "auto", scan_backend: str = "auto",
                 max_refine: int = MAX_REFINE,
                 comp0=None) -> CompiledCluster:
         ops = workload.build(self.spec.n_gateways)
@@ -97,7 +97,7 @@ class Cluster:
                              max_refine=max_refine, comp0=comp0)
 
     def run(self, workload: ClusterWorkload, *, down: Optional[int] = None,
-            sweeps: int = 512, fixpoint: str = "loop",
+            sweeps: int = 512, fixpoint: str = "auto",
             scan_backend: str = "auto",
             max_refine: int = MAX_REFINE) -> ClusterRunResult:
         compiled = self.compile(workload, down=down, sweeps=sweeps,

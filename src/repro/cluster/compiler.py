@@ -36,7 +36,8 @@ Lowering runs in two steps shared with the differential oracle:
 The compiled per-config programs are pure data: the capacity planner
 concatenates dozens of them (:func:`repro.core.concat_programs`) and
 solves the whole rack sweep in ONE :func:`repro.core.solve_program`
-call — on the fused fixpoint kernels when JAX/TPU is available.
+call — on the chip's float64 XLA fixpoint on a TPU
+(:mod:`repro.core.platform` chooses).
 """
 from __future__ import annotations
 
@@ -468,7 +469,7 @@ def _warm_refined_solve(program: ChainProgram, graph: ClusterGraph,
 
 
 def compile_graph(graph: ClusterGraph, *, sweeps: int = 512,
-                  fixpoint: str = "loop", scan_backend: str = "auto",
+                  fixpoint: str = "auto", scan_backend: str = "auto",
                   max_refine: int = MAX_REFINE,
                   comp0: Optional[np.ndarray] = None,
                   order_seed: Optional[np.ndarray] = None,

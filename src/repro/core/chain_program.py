@@ -49,10 +49,10 @@ host layer's ``compare_policies()`` stop re-lowering identical traces.
 :func:`solve_program` runs the fused fixpoint: the numpy driver
 iterates family blocks with the batched float64 doubling scan
 (:func:`repro.core.engine.zone_sequential_completions_batched`); the
-``"xla"``/``"pallas"`` drivers hand the whole program to
-``repro.kernels.zns_fixpoint`` -- a jitted ``lax.while_loop`` (or the
-Pallas TPU kernel) iterating all sweeps x families in-kernel with an
-early-exit ``moved`` reduction.
+``"xla"`` driver hands the whole program to
+``repro.kernels.zns_fixpoint`` -- a jitted float64 ``lax.while_loop``
+iterating all sweeps x families on the device with an early-exit
+``moved`` reduction.  :mod:`repro.core.platform` picks the driver.
 """
 from __future__ import annotations
 
@@ -61,7 +61,6 @@ import hashlib
 import heapq
 import os
 import pickle
-import sys
 import tempfile
 import time
 import warnings
@@ -72,8 +71,9 @@ import numpy as np
 
 from .engine import (
     Trace, compute_service_times, trace_chain_families,
-    zone_sequential_completions_batched, _on_tpu,
+    zone_sequential_completions_batched,
 )
+from . import platform
 from .fleet import length_buckets
 from .latency import resolve_params
 from .spec import OpType, ZNSDeviceSpec
@@ -325,7 +325,9 @@ class SolveStats:
     sweep entirely); ``residuals[s]`` is the largest completion-time
     increase any event saw during that sweep (``0.0`` on a pure
     verification sweep).  Kernel and sharded drivers report the sweep
-    count and leave the per-sweep trajectories empty.
+    count and leave the per-sweep trajectories empty.  ``devices`` names
+    the jax devices the solve's result came from (empty for the host's
+    numpy drivers).
     """
 
     driver: str = "loop"
@@ -334,12 +336,14 @@ class SolveStats:
     n_blocks: int = 0
     active_blocks: Tuple[int, ...] = ()
     residuals: Tuple[float, ...] = ()
+    devices: Tuple[str, ...] = ()
 
     def to_json(self) -> Dict[str, object]:
         return {"driver": self.driver, "sweeps": self.sweeps,
                 "converged": self.converged, "n_blocks": self.n_blocks,
                 "active_blocks": list(self.active_blocks),
-                "residuals": list(self.residuals)}
+                "residuals": list(self.residuals),
+                "devices": list(self.devices)}
 
 
 _LAST_SOLVE_STATS = SolveStats()
@@ -1453,17 +1457,24 @@ def _solve_numpy(program: ChainProgram, svc_flat: np.ndarray, *,
 def _solve_kernel(program: ChainProgram, svc_flat: np.ndarray, *,
                   sweeps: int, impl: str,
                   comp0: Optional[np.ndarray] = None
-                  ) -> Tuple[np.ndarray, int, bool]:
+                  ) -> Tuple[np.ndarray, int, bool, Tuple[str, ...]]:
+    """Whole-program fixpoint in one jitted call, in float64 (x64 is
+    scoped to the solve).  Also returns the devices the result is on."""
+    import jax
+
     from repro.kernels import ops as kops
     init = program.issue_flat + svc_flat
     if comp0 is not None:
         init = np.maximum(init, comp0)
-    comp, used, converged = kops.zns_fixpoint(
-        init, svc_flat,
-        tuple(blk.rows_view() for blk in program.families),
-        sweeps=max(int(sweeps), 1), impl=impl,
-        adj=block_adjacency(program))
-    return (np.asarray(comp, dtype=np.float64), int(used), bool(converged))
+    with jax.enable_x64(True):
+        comp, used, converged = kops.zns_fixpoint(
+            init, svc_flat,
+            tuple(blk.rows_view() for blk in program.families),
+            sweeps=max(int(sweeps), 1), impl=impl,
+            adj=block_adjacency(program))
+        devices = tuple(sorted(str(d) for d in comp.devices()))
+        return (np.asarray(comp, dtype=np.float64), int(used),
+                bool(converged), devices)
 
 
 def verify_fixpoint(program: ChainProgram, svc_flat: np.ndarray,
@@ -1535,27 +1546,6 @@ def unjustified_slots(program: ChainProgram, svc_flat: np.ndarray,
     return np.nonzero(comp - target > tol)[0]
 
 
-def _auto_sharded() -> bool:
-    """True when the ``auto`` driver should shard: jax is already
-    loaded with >1 local devices on an accelerator platform.  Never on
-    CPU hosts — the single-chip numpy loop stays the (bit-identical)
-    default there.  ``REPRO_SHARD_EXECUTOR=mesh|host`` forces sharding
-    on; ``=off`` forces it off."""
-    forced = os.environ.get("REPRO_SHARD_EXECUTOR", "").lower()
-    if forced in ("mesh", "host"):
-        return True
-    if forced in ("off", "none", "0"):
-        return False
-    if "jax" not in sys.modules:
-        return False
-    try:
-        import jax
-        devs = jax.local_devices()
-        return len(devs) > 1 and devs[0].platform != "cpu"
-    except Exception:
-        return False
-
-
 def solve_program(program: ChainProgram, svc_flat: np.ndarray, *,
                   sweeps: int = 8, scan_backend: str = "auto",
                   fixpoint: str = "auto", warn: bool = True,
@@ -1567,18 +1557,21 @@ def solve_program(program: ChainProgram, svc_flat: np.ndarray, *,
     ``fixpoint`` selects the driver: ``"loop"`` iterates family blocks
     in Python around the batched scan (float64; ``scan_backend`` as in
     :func:`repro.core.engine.zone_sequential_completions_batched`),
-    ``"xla"`` / ``"pallas"`` run all sweeps x families in one jitted
-    ``lax.while_loop`` / Pallas kernel (float32,
-    ``repro.kernels.zns_fixpoint``); ``"sharded"`` partitions the
+    ``"xla"`` runs all sweeps x families in one jitted float64
+    ``lax.while_loop`` on the default jax device
+    (``repro.kernels.zns_fixpoint``); ``"pallas"`` / ``"interpret"``
+    run the Pallas form of that loop (refused by the TPU compiler, so
+    reachable only by name); ``"sharded"`` partitions the
     entry axis across shards (:mod:`repro.core.shard`) — the mesh
     executor spreads them over local jax devices via ``shard_map``,
     the host executor groups them into signature buckets with
     independent convergence; ``"windowed"`` partitions the *request*
     axis of a single mega-entry into issue-time windows solved as a
     pipeline (:func:`repro.core.shard.solve_program_windowed`) with
-    per-window bounded memory; ``"auto"`` picks the kernel on TPU, the
-    sharded driver on multi-chip accelerator hosts for multi-device
-    programs, and the float64 loop elsewhere.  Every driver records
+    per-window bounded memory; ``"auto"`` asks
+    :func:`repro.core.platform.fixpoint_driver`: the sharded driver for
+    a multi-entry program on a multi-chip accelerator host, ``"xla"`` on
+    a TPU, the float64 loop elsewhere.  Every driver records
     :class:`SolveStats` telemetry, readable via
     :func:`last_solve_stats`.  When the sweep budget
     is exhausted while constraints are still moving the result is a
@@ -1599,10 +1592,7 @@ def solve_program(program: ChainProgram, svc_flat: np.ndarray, *,
         raise ValueError(f"service vector has {len(svc_flat)} entries for a "
                          f"{program.n_flat}-request program")
     if fixpoint == "auto":
-        fixpoint = "pallas" if _on_tpu() else "loop"
-        if fixpoint == "loop" and program.n_devices > 1 \
-                and _auto_sharded():
-            fixpoint = "sharded"
+        fixpoint = platform.fixpoint_driver(program.n_devices)
     if comp0 is not None and len(comp0) != program.n_flat:
         raise ValueError(f"comp0 has {len(comp0)} entries for a "
                          f"{program.n_flat}-request program")
@@ -1623,13 +1613,13 @@ def solve_program(program: ChainProgram, svc_flat: np.ndarray, *,
             sweeps=sweeps, scan_backend=scan_backend, comp0=comp0,
             warn=False)
     elif fixpoint in ("xla", "pallas", "interpret"):
-        comp, used, converged = _solve_kernel(
+        comp, used, converged, devices = _solve_kernel(
             program, np.asarray(svc_flat, dtype=np.float64),
             sweeps=sweeps, impl=fixpoint, comp0=comp0)
         global _LAST_SOLVE_STATS
         _LAST_SOLVE_STATS = SolveStats(
             driver=fixpoint, sweeps=used, converged=converged,
-            n_blocks=len(program.families))
+            n_blocks=len(program.families), devices=devices)
     else:
         raise ValueError(f"unknown fixpoint driver {fixpoint!r}; expected "
                          f"auto | loop | sharded | windowed | xla | "

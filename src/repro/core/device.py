@@ -12,8 +12,8 @@ the closed-form :class:`ThroughputModel`, and runs declarative
   greedy server assignment); reference semantics.
 * ``"vectorized"`` — the trace compiles (once, content-cached) into a
   :class:`repro.core.ChainProgram` solved by one fused max-plus
-  fixpoint (the Pallas ``zns_fixpoint`` kernel on TPU, the batched
-  float64 doubling scan elsewhere); order-of-magnitude faster on large
+  fixpoint (the float64 XLA fixpoint on a TPU, the batched float64
+  doubling scan elsewhere); order-of-magnitude faster on large
   traces and, on jitter-free runs, exact even on saturated
   single-service-class pools.
 * ``"auto"``       — vectorized for large traces, event otherwise

@@ -26,16 +26,15 @@ shims for existing callers).  Three complementary engines, all built on
   on saturated single-service-class pools (multi-thread append pools).
 
 The per-zone sequential-completion recurrence that dominates large traces
-(``c_i = max(c_{i-1}, s_i) + v_i``) is a max-plus linear scan; the TPU
-Pallas kernel ``repro.kernels.zns_event_scan`` implements it blocked, and
-:func:`zone_sequential_completions` dispatches to it (with a vectorized
-float64 numpy doubling scan as the CPU path).
+(``c_i = max(c_{i-1}, s_i) + v_i``) is a max-plus linear scan;
+:func:`zone_sequential_completions` runs it as a vectorized float64 numpy
+doubling scan.  The blocked Pallas form (``repro.kernels.zns_event_scan``)
+is reachable only as ``backend="pallas"``: the TPU compiler refuses it.
 """
 from __future__ import annotations
 
 import dataclasses
 import heapq
-import sys
 from typing import Optional, Tuple
 
 import numpy as np
@@ -421,23 +420,20 @@ def zone_sequential_completions(issue, svc, segment_starts, *, backend="auto"):
     """Per-zone sequential completion times: c_i = max(c_{i-1}, s_i) + v_i.
 
     ``segment_starts``: bool array marking the first request of each zone
-    segment (requests must be grouped by zone).  Backends: ``"pallas"``
-    forces the TPU kernel (float32), ``"numpy"`` the vectorized float64
-    doubling scan, ``"python"`` the sequential oracle; ``"auto"`` uses the
-    Pallas kernel on TPU and the numpy scan elsewhere.
+    segment (requests must be grouped by zone).  Backends: ``"numpy"``
+    (and ``"auto"``) the vectorized float64 doubling scan, ``"python"``
+    the sequential oracle, ``"pallas"`` the float32 Pallas TPU kernel —
+    which the TPU compiler refuses, so only this explicit name reaches
+    it, and its error propagates.
     """
-    if backend == "pallas" or (backend == "auto" and _on_tpu()):
-        try:
-            from repro.kernels import ops as kops
-            import jax.numpy as jnp
-            out = kops.zns_event_scan(
-                jnp.asarray(issue, dtype=jnp.float32),
-                jnp.asarray(svc, dtype=jnp.float32),
-                jnp.asarray(segment_starts, dtype=bool))
-            return np.asarray(out, dtype=np.float64)
-        except Exception:
-            if backend == "pallas":
-                raise
+    if backend == "pallas":
+        from repro.kernels import ops as kops
+        import jax.numpy as jnp
+        out = kops.zns_event_scan(
+            jnp.asarray(issue, dtype=jnp.float32),
+            jnp.asarray(svc, dtype=jnp.float32),
+            jnp.asarray(segment_starts, dtype=bool), impl="pallas")
+        return np.asarray(out, dtype=np.float64)
     issue = np.asarray(issue, dtype=np.float64)
     svc = np.asarray(svc, dtype=np.float64)
     seg = np.asarray(segment_starts, dtype=bool)
@@ -491,23 +487,20 @@ def zone_sequential_completions_batched(issue, svc, segment_starts, *,
     """Batched :func:`zone_sequential_completions` over (B, L) arrays.
 
     Each row is an independent set of serialized segments (rows never
-    share a carry).  Backends mirror the 1-D dispatch: ``"pallas"`` forces
-    the TPU kernel's batch grid dimension, ``"numpy"`` the batched float64
-    doubling scan, ``"python"`` the per-row sequential oracle; ``"auto"``
-    uses Pallas on TPU (``jax.vmap``-style batch grid) and numpy elsewhere.
+    share a carry).  Backends mirror the 1-D dispatch: ``"numpy"`` (and
+    ``"auto"``) the batched float64 doubling scan, ``"python"`` the
+    per-row sequential oracle, ``"pallas"`` the Pallas kernel's batch
+    grid dimension (explicit name only; see
+    :func:`zone_sequential_completions`).
     """
-    if backend == "pallas" or (backend == "auto" and _on_tpu()):
-        try:
-            from repro.kernels import ops as kops
-            import jax.numpy as jnp
-            out = kops.zns_event_scan_batched(
-                jnp.asarray(issue, dtype=jnp.float32),
-                jnp.asarray(svc, dtype=jnp.float32),
-                jnp.asarray(segment_starts, dtype=bool))
-            return np.asarray(out, dtype=np.float64)
-        except Exception:
-            if backend == "pallas":
-                raise
+    if backend == "pallas":
+        from repro.kernels import ops as kops
+        import jax.numpy as jnp
+        out = kops.zns_event_scan_batched(
+            jnp.asarray(issue, dtype=jnp.float32),
+            jnp.asarray(svc, dtype=jnp.float32),
+            jnp.asarray(segment_starts, dtype=bool), impl="pallas")
+        return np.asarray(out, dtype=np.float64)
     if backend != "python":
         return _maxplus_scan_numpy_batched(issue, svc, segment_starts)
     issue = np.asarray(issue, dtype=np.float64)
@@ -516,26 +509,6 @@ def zone_sequential_completions_batched(issue, svc, segment_starts, *,
     return np.stack([zone_sequential_completions(issue[i], svc[i], seg[i],
                                                  backend="python")
                      for i in range(issue.shape[0])])
-
-
-_ON_TPU: Optional[bool] = None
-
-
-def _on_tpu() -> bool:
-    # Only consult jax once something else has imported it: dragging the
-    # whole jax runtime in for a CPU-side numpy scan costs ~1 s.  The
-    # answer is only cached after jax is available, so early CPU-path
-    # calls don't pin the dispatch before jax initializes.
-    global _ON_TPU
-    if _ON_TPU is None:
-        jax = sys.modules.get("jax")
-        if jax is None:
-            return False
-        try:
-            _ON_TPU = jax.default_backend() == "tpu"
-        except Exception:
-            return False
-    return _ON_TPU
 
 
 # ---------------------------------------------------------------------------
@@ -650,9 +623,9 @@ def simulate_vectorized(trace: Trace, spec: ZNSDeviceSpec = ZNSDeviceSpec(),
     server-pool chains split per service class and ordered by the event
     heap's pop order.  The compiled program is then solved by one fused
     Gauss–Seidel fixpoint of batched segmented max-plus scans
-    (:func:`repro.core.chain_program.solve_program`): the Pallas
-    ``zns_fixpoint`` kernel on TPU, the batched float64 numpy doubling
-    scan elsewhere.  ``sweeps`` bounds the iteration; exhaustion sets
+    (:func:`repro.core.chain_program.solve_program`): the float64 XLA
+    fixpoint on a TPU, the batched float64 numpy doubling scan
+    elsewhere.  ``sweeps`` bounds the iteration; exhaustion sets
     ``SimResult.converged = False`` and warns.
 
     Exact (to float tolerance) versus :func:`simulate` whenever the

@@ -9,8 +9,8 @@ chains, metadata engine, pop-ordered per-service-class pool chains —
 concatenate into fleet-wide length-bucketed ``(R, L)`` family blocks
 addressing one flat completion vector, and the whole fleet solves as a
 single fused Gauss–Seidel fixpoint of batched segmented max-plus scans
-(the Pallas ``zns_fixpoint`` kernel on TPU, the batched float64 numpy
-doubling scan elsewhere).
+(the float64 XLA ``zns_fixpoint_xla`` loop on a TPU, the batched
+float64 numpy doubling scan elsewhere).
 
 Per-device results are bit-compatible with single-device runs: service
 times draw from per-device seeds in the same rng order, lowering is
@@ -120,12 +120,13 @@ def simulate_fleet_vectorized(traces: Sequence[Trace],
     (:func:`repro.core.chain_program.solve_program`): one kernel launch
     for N heterogeneous devices instead of ``sweeps × families ×
     devices`` dispatches.  On hosts with more than one local jax
-    accelerator device, ``fixpoint="auto"`` routes the solve through
+    accelerator device (:mod:`repro.core.platform`),
+    ``fixpoint="auto"`` routes the solve through
     the entry-sharded driver (:mod:`repro.core.shard`) — per-shard
     convergence budgets, ``shard_map`` over the local mesh — so fleet
     callers (``DeviceFleet.run``, the experiment runner, the capacity
     planner) scale out transparently; pass ``fixpoint="loop"`` to pin
-    the single-chip solve, or ``"sharded"`` to force the sharded one.
+    the host's float64 numpy solve, or ``"sharded"`` to force the sharded one.
 
     ``lats[i]`` may be a :class:`LatencyModel` or bare
     :class:`LatencyParams`.  ``seeds[i]`` defaults to ``i`` so device ``i``

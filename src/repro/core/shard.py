@@ -26,16 +26,13 @@ Partitioning is safe by construction: entries are the connected
 components of the chain/device incidence graph (a union-find pass), so
 a family added by ``extend_program`` that couples two devices simply
 fuses them into one shard.  ``solve_program(fixpoint="auto")`` routes
-here only on multi-chip accelerator hosts; on CPU the single-chip numpy
-driver stays the default and a 1-shard plan falls back to it
-bit-identically.  Force an executor with ``REPRO_SHARD_EXECUTOR=mesh``
-/ ``host`` / ``off`` (tests and the mega-fleet benchmark use this).
+here only on multi-chip accelerator hosts (:mod:`repro.core.platform`);
+on CPU the single-chip numpy driver stays the default.  A 1-shard plan
+falls back to the single-chip solve, bit-identically.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
-import sys
 import warnings
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -44,12 +41,10 @@ import numpy as np
 
 import hashlib
 
+from . import platform
 from .chain_program import (ChainProgram, SolveStats, _blocks_from_chains,
-                            _solve_numpy, block_adjacency, program_chains)
-
-#: Environment override for the sharded executor: ``mesh`` | ``host``
-#: force one, ``off`` disables auto-sharding in ``solve_program``.
-EXECUTOR_ENV = "REPRO_SHARD_EXECUTOR"
+                            _solve_numpy, block_adjacency, last_solve_stats,
+                            program_chains, solve_program)
 
 #: The host executor merges the smallest signature groups until at most
 #: this many shards remain — each shard is one numpy sub-solve, and
@@ -305,21 +300,6 @@ def clear_shard_plans() -> None:
     _PLAN_CACHE.clear()
 
 
-def _pick_executor() -> str:
-    forced = os.environ.get(EXECUTOR_ENV, "").lower()
-    if forced in ("mesh", "host"):
-        return forced
-    if "jax" in sys.modules:
-        try:
-            import jax
-            devs = jax.local_devices()
-            if len(devs) > 1 and devs[0].platform != "cpu":
-                return "mesh"
-        except Exception:
-            pass
-    return "host"
-
-
 # ---------------------------------------------------------------------------
 # Executors
 # ---------------------------------------------------------------------------
@@ -395,17 +375,20 @@ def _mesh_static(plan: ShardedProgram, ndev: int) -> dict:
 
 def _solve_mesh(program: ChainProgram, svc: np.ndarray, *, sweeps: int,
                 scan_backend: str, comp0: Optional[np.ndarray]
-                ) -> Tuple[np.ndarray, int, bool]:
+                ) -> Tuple[np.ndarray, int, bool, Tuple[str, ...]]:
+    """Shards across every local jax device; also returns the devices
+    the shards' results came from."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.kernels.zns_fixpoint import zns_fixpoint_sharded
 
-    devices = tuple(jax.local_devices())
+    devices = platform.probe()[1]
     plan = _plan(program, len(devices))
     if len(plan.shards) <= 1:
-        return _solve_numpy(program, svc, sweeps=sweeps,
-                            scan_backend=scan_backend, comp0=comp0)
+        comp, used, conv = solve_program(
+            program, svc, sweeps=sweeps, scan_backend=scan_backend,
+            fixpoint=platform.single_chip_driver(), warn=False, comp0=comp0)
+        return comp, used, conv, last_solve_stats().devices
     st = _mesh_static(plan, len(devices))
     S, n_max = st["S"], st["n_max"]
     init = np.full((S, n_max + 1), -np.inf, dtype=np.float64)
@@ -417,10 +400,12 @@ def _solve_mesh(program: ChainProgram, svc: np.ndarray, *, sweeps: int,
             c0 = np.maximum(c0, comp0[sh.perm])
         init[s, :len(v)] = c0
         svcS[s, :len(v)] = v
-    with enable_x64():
+    with jax.enable_x64(True):
         comp_s, used_s, conv_s = zns_fixpoint_sharded(
             init, svcS, st["blocks"], sweeps=sweeps, devices=devices,
             adj=st["adj"])
+        used_on = tuple(sorted({str(sh.device)
+                                for sh in comp_s.addressable_shards}))
         comp_s = np.asarray(comp_s, dtype=np.float64)
         used_s = np.asarray(used_s)
         conv_s = np.asarray(conv_s)
@@ -428,7 +413,7 @@ def _solve_mesh(program: ChainProgram, svc: np.ndarray, *, sweeps: int,
     for s, sh in enumerate(plan.shards):
         comp[sh.perm] = comp_s[s, :len(sh.perm)]
     n = len(plan.shards)
-    return comp, int(used_s[:n].max()), bool(conv_s[:n].all())
+    return comp, int(used_s[:n].max()), bool(conv_s[:n].all()), used_on
 
 
 def solve_program_sharded(program: ChainProgram, svc_flat, *,
@@ -442,11 +427,11 @@ def solve_program_sharded(program: ChainProgram, svc_flat, *,
     object) and solves each shard independently — the fixpoint is
     block-diagonal over entries, so the result equals the single-chip
     solve to float64 fixpoint tolerance (~1e-12 relative; a 1-shard
-    plan falls back to the numpy driver bit-identically).  ``executor``
-    = ``"host"`` (signature-grouped numpy sub-solves), ``"mesh"``
-    (``shard_map`` across local jax devices), or ``"auto"`` (mesh on
-    multi-chip accelerator hosts, host otherwise;
-    ``REPRO_SHARD_EXECUTOR`` overrides).
+    plan falls back to the single-chip driver bit-identically).
+    ``executor`` = ``"host"`` (signature-grouped numpy sub-solves),
+    ``"mesh"`` (``shard_map`` across local jax devices), or ``"auto"``
+    (:func:`repro.core.platform.shard_executor`: mesh on multi-chip
+    accelerator hosts, host otherwise).
     """
     svc = np.asarray(svc_flat, dtype=np.float64)
     if program.n_flat == 0:
@@ -461,19 +446,20 @@ def solve_program_sharded(program: ChainProgram, svc_flat, *,
         raise ValueError(f"unknown shard executor {executor!r}; "
                          f"expected auto | host | mesh")
     if executor == "auto":
-        executor = _pick_executor()
+        executor = platform.shard_executor()
+    devices: Tuple[str, ...] = ()
     if executor == "host" or program.n_devices <= 1:
         comp, used, conv = _solve_host(program, svc, sweeps=sweeps,
                                        scan_backend=scan_backend,
                                        comp0=comp0)
     else:
-        comp, used, conv = _solve_mesh(program, svc, sweeps=sweeps,
-                                       scan_backend=scan_backend,
-                                       comp0=comp0)
+        comp, used, conv, devices = _solve_mesh(
+            program, svc, sweeps=sweeps, scan_backend=scan_backend,
+            comp0=comp0)
     import repro.core.chain_program as _cp
     _cp._LAST_SOLVE_STATS = SolveStats(
         driver=f"sharded/{executor}", sweeps=used, converged=conv,
-        n_blocks=len(program.families))
+        n_blocks=len(program.families), devices=devices)
     if not conv and warn:
         warnings.warn(
             f"sharded chain-program fixpoint exhausted its sweep budget "

@@ -26,7 +26,8 @@ import math
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from repro.core import DeviceFleet, LatencyModel, RunResult, ZnsDevice
+from repro.core import (DeviceFleet, FleetRunResult, LatencyModel,
+                        RunResult, ZnsDevice)
 
 from .registry import Check, Experiment, resolve_experiments
 
@@ -115,17 +116,26 @@ class ExperimentRunner:
     def run(self) -> List[ExperimentResult]:
         """One fleet-batched simulation of every sweep point, then
         per-experiment extraction and checks."""
-        points = [(exp, pt) for exp in self.experiments
-                  for pt in exp.points]
-        if not points:
+        if not any(exp.points for exp in self.experiments):
             return []
+        return self.evaluate(self.simulate())
+
+    def simulate(self, **fleet_opts) -> FleetRunResult:
+        """The one :class:`DeviceFleet` call behind :meth:`run`, one
+        member per sweep point; ``fleet_opts`` go to
+        :meth:`DeviceFleet.run` (e.g. ``fixpoint="loop"``)."""
+        points = [pt for exp in self.experiments for pt in exp.points]
         fleet = DeviceFleet(
             [(pt.spec, pt.params) if pt.params is not None else pt.spec
-             for _, pt in points])
-        fres = fleet.run([pt.workload for _, pt in points],
+             for pt in points])
+        return fleet.run([pt.workload for pt in points],
                          backend=self.backend,
-                         seeds=[self.seed + pt.seed for _, pt in points],
-                         jitter=self.jitter)
+                         seeds=[self.seed + pt.seed for pt in points],
+                         jitter=self.jitter, **fleet_opts)
+
+    def evaluate(self, fres: FleetRunResult) -> List[ExperimentResult]:
+        """Per-experiment extraction and checks of a :meth:`simulate`
+        result."""
         out: List[ExperimentResult] = []
         i = 0
         for exp in self.experiments:

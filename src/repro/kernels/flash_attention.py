@@ -20,8 +20,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -76,7 +74,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     static_argnames=("causal", "window", "scale", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     scale: float | None = None, bq: int = 128, bk: int = 256,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q: (B, Hq, Tq, D); k/v: (B, Hkv, Tk, D) -> (B, Hq, Tq, D)."""
     b, hq, tq, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
@@ -112,7 +110,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

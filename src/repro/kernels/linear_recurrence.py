@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _kernel(a_ref, b_ref, o_ref, h_ref, *, bt):
     ti = pl.program_id(1)
@@ -42,7 +40,7 @@ def _kernel(a_ref, b_ref, o_ref, h_ref, *, bt):
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
-def linear_recurrence(a, b, *, block_t: int = 256, interpret: bool = True):
+def linear_recurrence(a, b, *, block_t: int = 256, interpret: bool = False):
     """a, b: (B, T, D) -> h: (B, T, D) with h_t = a_t h_{t-1} + b_t."""
     bb, t, d = a.shape
     bt = min(block_t, t)
@@ -60,7 +58,7 @@ def linear_recurrence(a, b, *, block_t: int = 256, interpret: bool = True):
         out_specs=pl.BlockSpec((1, bt, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bb, t_p, d), b.dtype),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(a_p, b_p)

@@ -1,17 +1,16 @@
 """Public jit'd wrappers for the kernel package.
 
-Every op takes ``impl`` (or infers it): 'pallas' runs the Pallas kernel
-compiled for TPU, 'interpret' runs the kernel body in interpret mode
-(CPU correctness), 'xla' runs the pure-jnp oracle from ref.py.  The
-default 'auto' picks 'pallas' on TPU backends and 'xla' elsewhere — the
-multi-pod dry-run therefore lowers the XLA path, while kernel tests pin
-'interpret' to exercise the kernel bodies.
+Every op takes ``impl``: 'pallas' runs the Pallas kernel compiled for
+TPU, 'interpret' runs the kernel body in interpret mode (CPU
+correctness), 'xla' runs the XLA form.  The model kernels' default
+'auto' asks :func:`repro.core.platform.kernel_impl` ('pallas' on TPU,
+'xla' elsewhere) — the multi-pod dry-run therefore lowers the XLA path,
+while kernel tests pin 'interpret' to exercise the kernel bodies.  The
+ZNS kernels default to 'xla' everywhere: the TPU compiler refuses their
+Pallas forms, which are reachable only by name.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 from . import ref
@@ -25,15 +24,11 @@ from .zns_fixpoint import zns_fixpoint as _zns_fixpoint
 from .zns_fixpoint import zns_fixpoint_xla as _zns_fixpoint_xla
 
 
-def _default_impl() -> str:
-    try:
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    except Exception:
-        return "xla"
-
-
 def _resolve(impl: str | None) -> str:
-    return impl if impl not in (None, "auto") else _default_impl()
+    if impl not in (None, "auto"):
+        return impl
+    from repro.core import platform
+    return platform.kernel_impl()
 
 
 def attention(q, k, v, *, causal=True, window=None, scale=None,
@@ -72,23 +67,21 @@ def ssd_scan(x, dt, A, B, C, *, chunk=128, impl: str | None = None):
     return _ssd(x, dt, A, B, C, chunk=chunk, interpret=(impl == "interpret"))
 
 
-def zns_event_scan(issue, svc, seg_start, *, impl: str | None = None):
-    impl = _resolve(impl)
+def zns_event_scan(issue, svc, seg_start, *, impl: str = "xla"):
     if impl == "xla":
         return ref.zns_event_scan_ref(issue, svc, seg_start)
     return _zns(issue, svc, seg_start, interpret=(impl == "interpret"))
 
 
-def zns_event_scan_batched(issue, svc, seg_start, *, impl: str | None = None):
-    """(B, N) device-batched max-plus scan (the DeviceFleet hot loop)."""
-    impl = _resolve(impl)
+def zns_event_scan_batched(issue, svc, seg_start, *, impl: str = "xla"):
+    """(B, N) device-batched max-plus scan."""
     if impl == "xla":
         return ref.zns_event_scan_batched_ref(issue, svc, seg_start)
     return _zns_batched(issue, svc, seg_start, interpret=(impl == "interpret"))
 
 
 def zns_fixpoint(comp0, svc, blocks, *, sweeps: int = 8,
-                 impl: str | None = None, adj=None):
+                 impl: str = "xla", adj=None):
     """Fused chain-program fixpoint: all sweeps × family blocks in one
     compiled call (the ``ZnsDevice``/``DeviceFleet`` vectorized-backend
     hot loop on TPU).
@@ -100,14 +93,14 @@ def zns_fixpoint(comp0, svc, blocks, *, sweeps: int = 8,
     omitted.  Returns ``(completions, sweeps_used, converged)``.
     ``impl='xla'`` runs the jitted ``lax.while_loop`` form,
     ``'pallas'``/``'interpret'`` the Pallas kernel (compiled / interpret
-    mode).
+    mode).  Computes in the dtype ``comp0`` has as a jax array: float64
+    under ``jax.enable_x64``, float32 otherwise.
     """
     from .zns_fixpoint import blocks_adjacency
-    impl = _resolve(impl)
     blocks = tuple((jnp.asarray(g, dtype=jnp.int32), jnp.asarray(h, bool))
                    for g, h in blocks)
-    comp0 = jnp.asarray(comp0, dtype=jnp.float32)
-    svc = jnp.asarray(svc, dtype=jnp.float32)
+    comp0 = jnp.asarray(comp0)
+    svc = jnp.asarray(svc, dtype=comp0.dtype)
     if adj is None:
         adj = blocks_adjacency([g for g, _ in blocks], comp0.shape[0])
     adj = jnp.asarray(adj, dtype=bool)

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _kernel(x_ref, w_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)
@@ -26,7 +24,7 @@ def _kernel(x_ref, w_ref, o_ref, *, eps):
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256,
-            interpret: bool = True):
+            interpret: bool = False):
     """x: (..., D), w: (D,) -> (..., D)."""
     orig_shape = x.shape
     d = orig_shape[-1]
@@ -44,7 +42,7 @@ def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, d), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, w)

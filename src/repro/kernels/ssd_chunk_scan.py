@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref, s_ref, *,
             nchunks):
@@ -73,7 +71,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref, s_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_chunk_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = True):
+def ssd_chunk_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
     """x:(Bb,T,H,P) dt:(Bb,T,H) A:(H,) B,C:(Bb,T,G,N) -> y:(Bb,T,H,P), S:(Bb,H,P,N).
 
     T must be a multiple of ``chunk`` (the model pads sequences).
@@ -110,7 +108,7 @@ def ssd_chunk_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = True):
             jax.ShapeDtypeStruct((bb, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(xh, dth, A.astype(jnp.float32), bh, ch)

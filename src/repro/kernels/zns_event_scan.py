@@ -84,7 +84,7 @@ def _kernel_batched(issue_ref, svc_ref, head_ref, out_ref, carry_ref):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def zns_event_scan_batched(issue, svc, seg_start, *, block: int = 1024,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """Batched completion times over a device axis: (B, N) inputs.
 
     The device-fleet counterpart of :func:`zns_event_scan` — one kernel
@@ -116,7 +116,7 @@ def zns_event_scan_batched(issue, svc, seg_start, *, block: int = 1024,
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def zns_event_scan(issue, svc, seg_start, *, block: int = 1024,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """Completion times for per-zone serialized requests.
 
     issue/svc: (N,) float32; seg_start: (N,) bool.  N is padded to a

@@ -26,16 +26,17 @@ This module runs that whole fixpoint as one compiled artifact instead of
   indices max-reduce harmlessly).
 * :func:`zns_fixpoint` — the Pallas form: the fixpoint core runs inside
   a single ``pallas_call`` with the flat completion vector resident in
-  kernel memory, so sweep iteration never round-trips to the host.
-  (Like the other kernels in this package it defaults to interpret mode
-  off-TPU; on TPU the blocks map to VMEM tiles with the while-loop
-  carried in-kernel.)
+  kernel memory.  It has no grid, so the whole vector and every gather
+  block would sit in VMEM, and the TPU compiler refuses it (its bool
+  output cannot be lowered); it runs in interpret mode on the CPU and is
+  reachable only by name.
 
-The semantic ground truth is ``repro.kernels.ref.zns_fixpoint_ref``
-(sequential per-row scans).  Production CPU solves use the float64
-numpy driver in :func:`repro.core.chain_program.solve_program`; these
-float32 kernels are the TPU path and are equivalence-tested against the
-oracle at float32 tolerance.
+Both keep the caller's dtype.  The TPU path is :func:`zns_fixpoint_xla`
+in float64 (the caller scopes ``jax.enable_x64``): float32 cannot hold
+the exactness contract's 1e-6 us on a trace that spans seconds.  The
+semantic ground truth is ``repro.kernels.ref.zns_fixpoint_ref``
+(sequential per-row scans); CPU solves use the float64 numpy driver in
+:func:`repro.core.chain_program.solve_program`.
 """
 from __future__ import annotations
 
@@ -186,6 +187,14 @@ def _fixpoint_core(comp_ext, svc_ext, blocks, sweeps: int, adj=None):
     return comp, used, jnp.any(active)
 
 
+def _extend(comp0, svc):
+    """Append the dead slot: the dtype's padding sentinel to the
+    completions, a zero service time."""
+    dt = comp0.dtype
+    return (jnp.append(comp0, _pad_value(dt)),
+            jnp.append(svc.astype(dt), jnp.zeros((), dt)))
+
+
 @functools.partial(jax.jit, static_argnames=("sweeps",))
 def zns_fixpoint_xla(comp0, svc, blocks, adj=None, *, sweeps: int = 8):
     """Fused fixpoint as a jitted ``lax.while_loop`` (no Pallas).
@@ -193,12 +202,11 @@ def zns_fixpoint_xla(comp0, svc, blocks, adj=None, *, sweeps: int = 8):
     ``comp0``: (n,) initial completions (``issue + svc``); ``svc``: (n,)
     service times; ``blocks``: tuple of ``(gidx int32 (R, L), heads
     bool (R, L))`` with padding indexed at ``n``; ``adj``: optional
-    ``(F, F)`` bool block adjacency for the active-set mask.  Returns
-    ``(comp (n,), sweeps_used, converged)``.
+    ``(F, F)`` bool block adjacency for the active-set mask.  Computes
+    in the dtype of ``comp0``.  Returns ``(comp (n,), sweeps_used,
+    converged)``.
     """
-    comp_ext = jnp.append(comp0.astype(jnp.float32),
-                          jnp.float32(NEG_INF))
-    svc_ext = jnp.append(svc.astype(jnp.float32), jnp.float32(0.0))
+    comp_ext, svc_ext = _extend(comp0, svc)
     comp, used, moved = _fixpoint_core(comp_ext, svc_ext, blocks, sweeps,
                                        adj)
     return comp[:-1], used, ~moved
@@ -223,7 +231,7 @@ def _kernel(comp_ref, svc_ref, adj_ref, *rest, sweeps: int):
 
 @functools.partial(jax.jit, static_argnames=("sweeps", "interpret"))
 def zns_fixpoint(comp0, svc, blocks, adj=None, *, sweeps: int = 8,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """Pallas form of :func:`zns_fixpoint_xla` (one ``pallas_call``).
 
     The flat completion vector stays resident across all sweeps ×
@@ -232,8 +240,7 @@ def zns_fixpoint(comp0, svc, blocks, adj=None, *, sweeps: int = 8,
     """
     n = comp0.shape[0]
     nf = len(blocks)
-    comp_ext = jnp.append(comp0.astype(jnp.float32), jnp.float32(NEG_INF))
-    svc_ext = jnp.append(svc.astype(jnp.float32), jnp.float32(0.0))
+    comp_ext, svc_ext = _extend(comp0, svc)
     if adj is None:
         adj = jnp.ones((nf, nf), bool) & ~jnp.eye(nf, dtype=bool)
     ins = [comp_ext, svc_ext, jnp.asarray(adj, dtype=bool)]
@@ -242,7 +249,7 @@ def zns_fixpoint(comp0, svc, blocks, adj=None, *, sweeps: int = 8,
     comp, used, conv = pl.pallas_call(
         functools.partial(_kernel, sweeps=max(int(sweeps), 1)),
         out_shape=(
-            jax.ShapeDtypeStruct((n + 1,), jnp.float32),
+            jax.ShapeDtypeStruct((n + 1,), comp_ext.dtype),
             jax.ShapeDtypeStruct((1,), jnp.int32),
             jax.ShapeDtypeStruct((1,), jnp.bool_),
         ),
@@ -277,19 +284,18 @@ def _stack_solve(comp0, svc, adj, *flat_blocks, sweeps: int):
 @functools.lru_cache(maxsize=8)
 def _sharded_fn(devices, n_arrays: int, sweeps: int):
     """Build (and cache) the jitted ``shard_map`` solver for a device
-    tuple.  ``check_rep=False`` is required: the per-shard
+    tuple.  ``check_vma=False`` is required: the per-shard
     ``lax.while_loop`` trip count is data-dependent, which the
-    replication checker cannot track."""
-    from jax.experimental.shard_map import shard_map
+    varying-axes checker cannot track."""
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(devices), ("shard",))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_stack_solve, sweeps=sweeps),
         mesh=mesh,
         in_specs=(P("shard"),) * n_arrays,
         out_specs=(P("shard"), P("shard"), P("shard")),
-        check_rep=False)
+        check_vma=False)
     # donate the completion buffer: it is overwritten every sweep and
     # the stacked (s, n_max + 1) float64 arrays are the footprint.
     # (CPU backends don't implement donation and warn; skip there.)

@@ -100,7 +100,7 @@ def test_zns_event_scan_sweep(n, block):
 
 
 def test_zns_event_scan_matches_numpy_engine_path():
-    """engine.zone_sequential_completions numpy fallback == kernel."""
+    """engine.zone_sequential_completions numpy path == kernel body."""
     from repro.core.engine import zone_sequential_completions
     n = 500
     issue = np.sort(RNG.uniform(0, 1e4, n))
@@ -108,8 +108,27 @@ def test_zns_event_scan_matches_numpy_engine_path():
     seg = RNG.uniform(size=n) < 0.1
     seg[0] = True
     a = zone_sequential_completions(issue, svc, seg, backend="numpy")
-    b = zone_sequential_completions(issue, svc, seg, backend="pallas")
-    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-2)
+    b = ops.zns_event_scan(jnp.asarray(issue, jnp.float32),
+                           jnp.asarray(svc, jnp.float32),
+                           jnp.asarray(seg), impl="interpret")
+    np.testing.assert_allclose(a, np.asarray(b), rtol=1e-5, atol=1e-2)
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_explicit_pallas_scan_backend_raises_off_tpu(batched):
+    """``backend="pallas"`` runs the compiled kernel or fails: off a TPU
+    Pallas refuses compiled mode, and nothing falls back to numpy."""
+    from repro.core.engine import (zone_sequential_completions,
+                                   zone_sequential_completions_batched)
+    shape = (2, 64) if batched else (64,)
+    issue = np.sort(RNG.uniform(0, 1e4, shape), axis=-1)
+    svc = RNG.uniform(1, 30, shape)
+    seg = np.zeros(shape, dtype=bool)
+    seg[..., 0] = True
+    fn = zone_sequential_completions_batched if batched \
+        else zone_sequential_completions
+    with pytest.raises(ValueError, match="interpret mode"):
+        fn(issue, svc, seg, backend="pallas")
 
 
 @pytest.mark.parametrize("bsz,n,block", [(1, 7, 256), (3, 1000, 256),
@@ -133,7 +152,7 @@ def test_zns_event_scan_batched_sweep(bsz, n, block):
 
 
 def test_zns_event_scan_batched_engine_dispatch():
-    """engine.zone_sequential_completions_batched numpy == pallas paths."""
+    """engine.zone_sequential_completions_batched numpy == kernel body."""
     from repro.core.engine import zone_sequential_completions_batched
     bsz, n = 4, 600
     issue = np.sort(RNG.uniform(0, 1e4, (bsz, n)), axis=1)
@@ -141,6 +160,7 @@ def test_zns_event_scan_batched_engine_dispatch():
     seg = RNG.uniform(size=(bsz, n)) < 0.1
     seg[:, 0] = True
     a = zone_sequential_completions_batched(issue, svc, seg, backend="numpy")
-    b = zone_sequential_completions_batched(issue, svc, seg,
-                                            backend="pallas")
-    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-2)
+    b = ops.zns_event_scan_batched(jnp.asarray(issue, jnp.float32),
+                                   jnp.asarray(svc, jnp.float32),
+                                   jnp.asarray(seg), impl="interpret")
+    np.testing.assert_allclose(a, np.asarray(b), rtol=1e-5, atol=1e-2)
