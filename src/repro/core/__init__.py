@@ -47,4 +47,4 @@ from .device import (  # noqa: F401
     ZnsDevice, available_backends, available_pressure_backends,
     register_backend, register_pressure_backend, unregister_backend,
 )
-from . import calibration, emulator_models, workloads  # noqa: F401
+from . import calibration, emulator_models, spans, workloads  # noqa: F401

@@ -62,7 +62,6 @@ import heapq
 import os
 import pickle
 import tempfile
-import time
 import warnings
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -73,7 +72,7 @@ from .engine import (
     Trace, compute_service_times, trace_chain_families,
     zone_sequential_completions_batched,
 )
-from . import platform
+from . import platform, spans
 from .fleet import length_buckets
 from .latency import resolve_params
 from .spec import OpType, ZNSDeviceSpec
@@ -235,7 +234,9 @@ class ChainProgram:
 # ---------------------------------------------------------------------------
 _PROGRAM_CACHE: "OrderedDict[tuple, ChainProgram]" = OrderedDict()
 _PROGRAM_CACHE_MAX = 8
-_CACHE_STATS = {"hits": 0, "misses": 0, "disk_hits": 0}
+#: The cache's counters in the span table (:mod:`repro.core.spans`).
+_CACHE_COUNTERS = ("program_cache.hits", "program_cache.misses",
+                   "program_cache.disk_hits")
 
 #: Identity fast path: recent ``(traces, specs, params, refine) ->
 #: program`` bindings keyed by trace object identity, so hot loops that
@@ -278,9 +279,10 @@ class CompileStats:
     ``hits``/``misses`` count in-memory program-cache lookups (LRU +
     identity fast path) since the cache was last cleared; ``disk_hits``
     counts programs loaded from the persistent on-disk cache;
-    ``lowering_ms`` is the wall-clock the last
-    :func:`compile_fleet_program` call spent lowering (0.0 on any cache
-    hit).  ``n_devices``/``n_unique`` expose the replica dedup: only
+    ``lowering_ms`` is the wall time of the last
+    :func:`compile_fleet_program` call, cache hits included: the time
+    of its ``lower`` span (:mod:`repro.core.spans`).
+    ``n_devices``/``n_unique`` expose the replica dedup: only
     ``n_unique`` of the ``n_devices`` member traces were lowered.
     """
 
@@ -324,10 +326,13 @@ class SolveStats:
     (converged blocks whose inputs did not change are dropped from the
     sweep entirely); ``residuals[s]`` is the largest completion-time
     increase any event saw during that sweep (``0.0`` on a pure
-    verification sweep).  Kernel and sharded drivers report the sweep
-    count and leave the per-sweep trajectories empty.  ``devices`` names
-    the jax devices the solve's result came from (empty for the host's
-    numpy drivers).
+    verification sweep).  The ``"xla"`` driver fills ``active_blocks``
+    from the kernel's own per-sweep count and leaves ``residuals``
+    empty; it runs no sweep once no block is active, so it never ends on
+    the loop driver's trailing sweep of 0 active blocks.  The Pallas and
+    sharded drivers report the sweep count and leave both trajectories
+    empty.  ``devices`` names the jax devices the solve's result came
+    from (empty for the host's numpy drivers).
     """
 
     driver: str = "loop"
@@ -400,7 +405,7 @@ def _disk_cache_get(key) -> Optional[ChainProgram]:
         return None
     if not isinstance(prog, ChainProgram):
         return None
-    _CACHE_STATS["disk_hits"] += 1
+    spans.count("program_cache.disk_hits")
     return prog
 
 
@@ -423,23 +428,28 @@ def _disk_cache_put(key, prog: ChainProgram) -> None:
 
 
 def program_cache_info() -> Dict[str, int]:
-    return dict(_CACHE_STATS, size=len(_PROGRAM_CACHE),
-                maxsize=_PROGRAM_CACHE_MAX)
+    """The program cache's hits, misses and disk hits since it was last
+    cleared (the ``program_cache.*`` counters of
+    :mod:`repro.core.spans`), its size and its capacity."""
+    table = spans.snapshot()
+    out = {k.split(".")[1]: table.get(k, {"total": 0})["total"]
+           for k in _CACHE_COUNTERS}
+    return dict(out, size=len(_PROGRAM_CACHE), maxsize=_PROGRAM_CACHE_MAX)
 
 
 def clear_program_cache() -> None:
     _PROGRAM_CACHE.clear()
     _IDENTITY_CACHE.clear()
-    _CACHE_STATS.update(hits=0, misses=0, disk_hits=0)
+    spans.reset(*_CACHE_COUNTERS)
 
 
 def _cache_get(key):
     prog = _PROGRAM_CACHE.get(key)
     if prog is not None:
         _PROGRAM_CACHE.move_to_end(key)
-        _CACHE_STATS["hits"] += 1
+        spans.count("program_cache.hits")
     else:
-        _CACHE_STATS["misses"] += 1
+        spans.count("program_cache.misses")
     return prog
 
 
@@ -880,9 +890,23 @@ def compile_fleet_program(traces: Sequence[Trace],
     per device, so replicas follow identical refinement trajectories.
     Mega-fleets replicating one workload over thousands of devices
     lower in O(unique) time.
+
+    The call is the ``lower`` span of :mod:`repro.core.spans`, and
+    ``last_compile_stats().lowering_ms`` is that span's time.
     """
     global _LAST_STATS
-    t0 = time.perf_counter()
+    with spans.span("lower") as sp:
+        prog, stats = _compile_fleet(traces, specs, lats, refine=refine,
+                                     cache=cache, dedup=dedup,
+                                     jitter=jitter, seeds=seeds)
+    _LAST_STATS = dataclasses.replace(stats, lowering_ms=sp.ns / 1e6)
+    return prog
+
+
+def _compile_fleet(traces, specs, lats, *, refine, cache, dedup, jitter,
+                   seeds) -> Tuple[ChainProgram, CompileStats]:
+    """:func:`compile_fleet_program`'s work: the program and its
+    :class:`CompileStats`, ``lowering_ms`` left for the caller."""
     B = len(traces)
     if not (len(specs) == len(lats) == B):
         raise ValueError(f"fleet shape mismatch: {B} traces, {len(specs)} "
@@ -906,12 +930,12 @@ def compile_fleet_program(traces: Sequence[Trace],
         if ihit is not None and all(a is b for a, b in
                                     zip(ihit[0], traces)):
             _IDENTITY_CACHE.move_to_end(ikey)
-            _CACHE_STATS["hits"] += 1
-            _LAST_STATS = CompileStats(hits=1, n_devices=B)
-            return ihit[1]
+            spans.count("program_cache.hits")
+            return ihit[1], CompileStats(hits=1, n_devices=B)
         # replicated workloads pass the same trace object many times;
         # digest each object once (and memoize on the trace itself)
-        digests = [_trace_digest(t) for t in traces]
+        with spans.span("lower.digest"):
+            digests = [_trace_digest(t) for t in traces]
         key = (tuple(digests), tuple(specs), tuple(params), int(refine),
                skey)
         hit = _cache_get(key)
@@ -925,15 +949,14 @@ def compile_fleet_program(traces: Sequence[Trace],
             _IDENTITY_CACHE[ikey] = (tuple(traces), hit)
             while len(_IDENTITY_CACHE) > _IDENTITY_CACHE_MAX:
                 _IDENTITY_CACHE.popitem(last=False)
-            _LAST_STATS = CompileStats(
-                hits=1 - disk, misses=disk, disk_hits=disk, n_devices=B,
-                lowering_ms=(time.perf_counter() - t0) * 1e3)
-            return hit
+            return hit, CompileStats(hits=1 - disk, misses=disk,
+                                     disk_hits=disk, n_devices=B)
 
     # --- replica dedup: lower + refine only the unique devices -------
     if dedup and B > 1:
         if digests is None:
-            digests = [_trace_digest(t) for t in traces]
+            with spans.span("lower.digest"):
+                digests = [_trace_digest(t) for t in traces]
         slot: Dict[tuple, int] = {}
         urep: List[int] = []            # unique slot -> first device idx
         rep: List[int] = []             # device idx -> unique slot
@@ -948,8 +971,9 @@ def compile_fleet_program(traces: Sequence[Trace],
     else:
         urep = list(range(B))
         rep = list(range(B))
-    udevs = [_lower_device(traces[b], specs[b], params[b],
-                           jitter=jitter, seed=seeds[b]) for b in urep]
+    with spans.span("lower.devices"):
+        udevs = [_lower_device(traces[b], specs[b], params[b],
+                               jitter=jitter, seed=seeds[b]) for b in urep]
     refine_used = 0
     order_stable = True
     unstable: List[str] = []
@@ -971,7 +995,7 @@ def compile_fleet_program(traces: Sequence[Trace],
                 f"issue-ordered bootstrap approximation; affected "
                 f"pools: {', '.join(unstable)}. Completions stay a "
                 f"convergent lower bound (exact=False); raise refine= "
-                f"to tighten.", RuntimeWarning, stacklevel=2)
+                f"to tighten.", RuntimeWarning, stacklevel=3)
     elif any(dev.needs_refine for dev in udevs):
 
         def _rebuild() -> List[str]:
@@ -998,9 +1022,10 @@ def compile_fleet_program(traces: Sequence[Trace],
         # refinement service vector, so a single rebuild freezes; the
         # second rebuild is the stability certificate (it must reproduce
         # the frozen chains — the replay is deterministic)
-        _rebuild()
-        refine_used = 1
-        unstable = sorted(set(_rebuild()))
+        with spans.span("lower.replay"):
+            _rebuild()
+            refine_used = 1
+            unstable = sorted(set(_rebuild()))
         order_stable = not unstable
         if not order_stable:
             warnings.warn(
@@ -1009,23 +1034,21 @@ def compile_fleet_program(traces: Sequence[Trace],
                 f"reproduce its own chains; unstable pools: "
                 f"{', '.join(unstable)}. Completions stay a convergent "
                 f"lower bound (exact=False).",
-                RuntimeWarning, stacklevel=2)
+                RuntimeWarning, stacklevel=3)
     exact = order_stable
     devs = [udevs[s] for s in rep]
-    prog = _assemble(devs, _family_lists(devs, include_reordered=True),
-                     exact=exact, refine_used=refine_used,
-                     order_stable=order_stable,
-                     unstable_pools=tuple(unstable), svc_seeds=skey)
+    with spans.span("lower.assemble"):
+        prog = _assemble(devs, _family_lists(devs, include_reordered=True),
+                         exact=exact, refine_used=refine_used,
+                         order_stable=order_stable,
+                         unstable_pools=tuple(unstable), svc_seeds=skey)
     if cache and key is not None:
         _cache_put(key, prog)
         _disk_cache_put(key, prog)
         _IDENTITY_CACHE[ikey] = (tuple(traces), prog)
         while len(_IDENTITY_CACHE) > _IDENTITY_CACHE_MAX:
             _IDENTITY_CACHE.popitem(last=False)
-    _LAST_STATS = CompileStats(
-        misses=1, n_devices=B, n_unique=len(urep),
-        lowering_ms=(time.perf_counter() - t0) * 1e3)
-    return prog
+    return prog, CompileStats(misses=1, n_devices=B, n_unique=len(urep))
 
 
 def compile_program(trace: Trace, spec: ZNSDeviceSpec, lat, *,
@@ -1451,30 +1474,49 @@ def _solve_numpy(program: ChainProgram, svc_flat: np.ndarray, *,
     _LAST_SOLVE_STATS = SolveStats(
         driver="loop", sweeps=used, converged=converged, n_blocks=nf,
         active_blocks=tuple(active_counts), residuals=tuple(residuals))
+    spans.count("solve.sweeps", used)
+    spans.count("solve.active_blocks", sum(active_counts))
     return comp[:-1], used, converged
 
 
 def _solve_kernel(program: ChainProgram, svc_flat: np.ndarray, *,
                   sweeps: int, impl: str,
                   comp0: Optional[np.ndarray] = None
-                  ) -> Tuple[np.ndarray, int, bool, Tuple[str, ...]]:
+                  ) -> Tuple[np.ndarray, int, bool, Tuple[str, ...],
+                             Tuple[int, ...]]:
     """Whole-program fixpoint in one jitted call, in float64 (x64 is
-    scoped to the solve).  Also returns the devices the result is on."""
+    scoped to the solve).  Also returns the devices the result is on
+    and, for ``impl="xla"``, the active blocks of each sweep run (empty
+    for the Pallas form)."""
     import jax
+    import jax.numpy as jnp
 
     from repro.kernels import ops as kops
-    init = program.issue_flat + svc_flat
-    if comp0 is not None:
-        init = np.maximum(init, comp0)
     with jax.enable_x64(True):
-        comp, used, converged = kops.zns_fixpoint(
-            init, svc_flat,
-            tuple(blk.rows_view() for blk in program.families),
-            sweeps=max(int(sweeps), 1), impl=impl,
-            adj=block_adjacency(program))
-        devices = tuple(sorted(str(d) for d in comp.devices()))
-        return (np.asarray(comp, dtype=np.float64), int(used),
-                bool(converged), devices)
+        with spans.span("solve.prepare"):
+            adj = jnp.asarray(block_adjacency(program))
+            init = program.issue_flat + svc_flat
+            if comp0 is not None:
+                init = np.maximum(init, comp0)
+            blocks = tuple((jnp.asarray(g, dtype=jnp.int32),
+                            jnp.asarray(h, dtype=bool))
+                           for g, h in (blk.rows_view()
+                                        for blk in program.families))
+            comp, used, converged, active = kops.zns_fixpoint(
+                jnp.asarray(init), jnp.asarray(svc_flat), blocks,
+                sweeps=max(int(sweeps), 1), impl=impl, adj=adj)
+        with spans.span("solve.wait"):
+            comp.block_until_ready()
+        with spans.span("solve.fetch"):
+            out = np.asarray(comp, dtype=np.float64)
+            used, converged = int(used), bool(converged)
+            active = () if active is None \
+                else tuple(int(a) for a in np.asarray(active)[:used])
+            devices = tuple(sorted(str(d) for d in comp.devices()))
+    spans.count("solve.sweeps", used)
+    if active:
+        spans.count("solve.active_blocks", sum(active))
+    return out, used, converged, devices, active
 
 
 def verify_fixpoint(program: ChainProgram, svc_flat: np.ndarray,
@@ -1613,13 +1655,14 @@ def solve_program(program: ChainProgram, svc_flat: np.ndarray, *,
             sweeps=sweeps, scan_backend=scan_backend, comp0=comp0,
             warn=False)
     elif fixpoint in ("xla", "pallas", "interpret"):
-        comp, used, converged, devices = _solve_kernel(
+        comp, used, converged, devices, active = _solve_kernel(
             program, np.asarray(svc_flat, dtype=np.float64),
             sweeps=sweeps, impl=fixpoint, comp0=comp0)
         global _LAST_SOLVE_STATS
         _LAST_SOLVE_STATS = SolveStats(
             driver=fixpoint, sweeps=used, converged=converged,
-            n_blocks=len(program.families), devices=devices)
+            n_blocks=len(program.families), active_blocks=active,
+            devices=devices)
     else:
         raise ValueError(f"unknown fixpoint driver {fixpoint!r}; expected "
                          f"auto | loop | sharded | windowed | xla | "

@@ -55,6 +55,7 @@ from .fleet import batched_sequential_completions, simulate_fleet_vectorized
 from .latency import LatencyModel, LatencyParams, stack_latency_params
 from .metrics import LatencyStats, bandwidth_bytes, extract_metrics, iops, \
     throughput_timeseries
+from . import spans
 from .spec import (
     ConvDeviceSpec, LBAFormat, MiB, OpType, Stack, ZNSDeviceSpec,
 )
@@ -707,64 +708,73 @@ class DeviceFleet:
         experiment runner stacks sweep points from unrelated experiments
         into one fleet call and pins each point's seed).
         """
-        traces = self._lower(workload, policy)
-        if seeds is None:
-            seeds = [seed + i for i in range(self.n)]
-        elif len(seeds) != self.n:
-            raise ValueError(f"got {len(seeds)} seeds for {self.n} devices")
-        total = sum(len(t) for t in traces)
-        name = _resolve_auto(total, self.auto_threshold) \
-            if backend == "auto" else backend
-        if name not in _BACKENDS:
-            raise KeyError(f"unknown backend {name!r}; available: "
-                           f"{available_backends()} (or 'auto')")
-        # The device-axis-batched engine implements the built-in
-        # "vectorized" backend; a third-party replacement of that name is
-        # honored by falling back to the per-device loop.
-        stats = sstats = None
-        if name == "vectorized" and _BACKENDS[name] is _vectorized_backend:
-            sims = simulate_fleet_vectorized(
-                traces, self.specs, [d.lat for d in self.devices],
-                seeds=list(seeds), jitter=jitter, **backend_opts)
-            stats = last_compile_stats()
-            sstats = last_solve_stats()
-        else:
-            # The per-device loop would emit one sweep-budget
-            # RuntimeWarning per device with no budget context; collapse
-            # them into a single fleet-level warning naming the
-            # offending entries (other warnings pass through untouched).
-            with warnings.catch_warnings(record=True) as caught:
-                warnings.simplefilter("always")
-                sims = [
-                    _BACKENDS[name](traces[i], self.devices[i].spec,
-                                    self.devices[i].lat, seed=seeds[i],
-                                    jitter=jitter, **backend_opts)
-                    for i in range(self.n)
-                ]
-            budget_hit = False
-            for w in caught:
-                if issubclass(w.category, RuntimeWarning) \
-                        and "sweep budget" in str(w.message):
-                    budget_hit = True
-                    continue
-                warnings.warn_explicit(w.message, w.category, w.filename,
-                                       w.lineno)
-            if budget_hit:
-                bad = [i for i in range(self.n) if not sims[i].converged]
-                used = [sims[i].sweeps_used for i in bad]
-                budget = backend_opts.get("sweeps", "the default")
-                warnings.warn(
-                    f"fleet sweep budget exhausted on {len(bad)} of "
-                    f"{self.n} devices (indices {bad}; sweeps_used="
-                    f"{used}, budget={budget}); those completions are "
-                    f"a lower bound. Raise sweeps= or inspect "
-                    f"FleetRunResult.converged.",
-                    RuntimeWarning, stacklevel=2)
-        results = tuple(RunResult(trace=traces[i], sim=sims[i], backend=name,
-                                  compile_stats=stats, solve_stats=sstats)
-                        for i in range(self.n))
-        return FleetRunResult(results=results, backend=name,
+        with spans.span("fleet.run"):
+            with spans.span("fleet.build"):
+                traces = self._lower(workload, policy)
+            if seeds is None:
+                seeds = [seed + i for i in range(self.n)]
+            elif len(seeds) != self.n:
+                raise ValueError(f"got {len(seeds)} seeds for {self.n} "
+                                 f"devices")
+            total = sum(len(t) for t in traces)
+            name = _resolve_auto(total, self.auto_threshold) \
+                if backend == "auto" else backend
+            if name not in _BACKENDS:
+                raise KeyError(f"unknown backend {name!r}; available: "
+                               f"{available_backends()} (or 'auto')")
+            # The device-axis-batched engine implements the built-in
+            # "vectorized" backend; a third-party replacement of that
+            # name is honored by falling back to the per-device loop.
+            stats = sstats = None
+            if name == "vectorized" \
+                    and _BACKENDS[name] is _vectorized_backend:
+                sims = simulate_fleet_vectorized(
+                    traces, self.specs, [d.lat for d in self.devices],
+                    seeds=list(seeds), jitter=jitter, **backend_opts)
+                stats = last_compile_stats()
+                sstats = last_solve_stats()
+            else:
+                # The per-device loop would emit one sweep-budget
+                # RuntimeWarning per device with no budget context;
+                # collapse them into a single fleet-level warning naming
+                # the offending entries (other warnings pass through
+                # untouched).
+                with warnings.catch_warnings(record=True) as caught:
+                    warnings.simplefilter("always")
+                    sims = [
+                        _BACKENDS[name](traces[i], self.devices[i].spec,
+                                        self.devices[i].lat, seed=seeds[i],
+                                        jitter=jitter, **backend_opts)
+                        for i in range(self.n)
+                    ]
+                budget_hit = False
+                for w in caught:
+                    if issubclass(w.category, RuntimeWarning) \
+                            and "sweep budget" in str(w.message):
+                        budget_hit = True
+                        continue
+                    warnings.warn_explicit(w.message, w.category,
+                                           w.filename, w.lineno)
+                if budget_hit:
+                    bad = [i for i in range(self.n)
+                           if not sims[i].converged]
+                    used = [sims[i].sweeps_used for i in bad]
+                    budget = backend_opts.get("sweeps", "the default")
+                    warnings.warn(
+                        f"fleet sweep budget exhausted on {len(bad)} of "
+                        f"{self.n} devices (indices {bad}; sweeps_used="
+                        f"{used}, budget={budget}); those completions are "
+                        f"a lower bound. Raise sweeps= or inspect "
+                        f"FleetRunResult.converged.",
+                        RuntimeWarning, stacklevel=2)
+            with spans.span("fleet.unpack"):
+                results = tuple(
+                    RunResult(trace=traces[i], sim=sims[i], backend=name,
                               compile_stats=stats, solve_stats=sstats)
+                    for i in range(self.n))
+                return FleetRunResult(results=results, backend=name,
+                                      compile_stats=stats,
+                                      solve_stats=sstats)
 
     def sequential_completions(self, issues, svcs, segment_starts, *,
                                backend: str = "auto") -> List[np.ndarray]:

@@ -26,6 +26,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from . import spans
 from .engine import (
     SimResult, Trace, compute_service_times,
     zone_sequential_completions_batched,
@@ -158,24 +159,26 @@ def simulate_fleet_vectorized(traces: Sequence[Trace],
     else:
         # jitter-free service times are part of the lowering output
         svc_flat = program.svc0_flat
-        svc_origs = [svc_flat[program.device_slice(b)][program.invs[b]]
-                     for b in range(B)]
     comp, used, converged = cp.solve_program(
         program, svc_flat, sweeps=sweeps, scan_backend=scan_backend,
         fixpoint=fixpoint, warn=False)
     if not converged:
         _warn_fleet_budget(program, svc_flat, comp, used, sweeps)
-    results = cp.unpack_results(program, comp, svc_flat, svc_origs)
-    # the compile-time exactness claim binds to the refinement service
-    # vector; a jittered solve of a jitter-free program (or a seed
-    # mismatch on a pre-compiled one) voids it
-    seeds_bind = tuple(int(s) for s in seeds) if jitter else None
-    claimed = bool(program.exact) and program.svc_seeds == seeds_bind
-    return [dataclasses.replace(
-        r, sweeps_used=used, converged=converged, exact=claimed,
-        order_stable=bool(program.order_stable),
-        unstable_pools=tuple(program.unstable_pools))
-        for r in results]
+    with spans.span("fleet.unpack"):
+        if not jitter:
+            svc_origs = [svc_flat[program.device_slice(b)][program.invs[b]]
+                         for b in range(B)]
+        results = cp.unpack_results(program, comp, svc_flat, svc_origs)
+        # the compile-time exactness claim binds to the refinement
+        # service vector; a jittered solve of a jitter-free program (or
+        # a seed mismatch on a pre-compiled one) voids it
+        seeds_bind = tuple(int(s) for s in seeds) if jitter else None
+        claimed = bool(program.exact) and program.svc_seeds == seeds_bind
+        return [dataclasses.replace(
+            r, sweeps_used=used, converged=converged, exact=claimed,
+            order_stable=bool(program.order_stable),
+            unstable_pools=tuple(program.unstable_pools))
+            for r in results]
 
 
 def batched_sequential_completions(issues: Sequence[np.ndarray],

@@ -27,7 +27,7 @@ import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.core import (DeviceFleet, FleetRunResult, LatencyModel,
-                        RunResult, ZnsDevice)
+                        RunResult, ZnsDevice, spans)
 
 from .registry import Check, Experiment, resolve_experiments
 
@@ -135,27 +135,28 @@ class ExperimentRunner:
 
     def evaluate(self, fres: FleetRunResult) -> List[ExperimentResult]:
         """Per-experiment extraction and checks of a :meth:`simulate`
-        result."""
-        out: List[ExperimentResult] = []
-        i = 0
-        for exp in self.experiments:
-            results = {pt.label: fres[i + j]
-                       for j, pt in enumerate(exp.points)}
-            i += len(exp.points)
-            first = exp.points[0]
-            dev = ZnsDevice(first.spec,
-                            lat=LatencyModel(first.spec, first.params)
-                            if first.params is not None else None)
-            ctx = ExperimentContext(experiment=exp, results=results,
-                                    device=dev, backend=fres.backend)
-            metrics = exp.extract(ctx)
-            checks = tuple(exp.check(metrics))
-            out.append(ExperimentResult(
-                experiment=exp, backend=fres.backend, metrics=metrics,
-                checks=checks,
-                n_requests=sum(len(r) for r in results.values()),
-                converged=all(r.converged for r in results.values())))
-        return out
+        result (the ``experiments.evaluate`` span)."""
+        with spans.span("experiments.evaluate"):
+            out: List[ExperimentResult] = []
+            i = 0
+            for exp in self.experiments:
+                results = {pt.label: fres[i + j]
+                           for j, pt in enumerate(exp.points)}
+                i += len(exp.points)
+                first = exp.points[0]
+                dev = ZnsDevice(first.spec,
+                                lat=LatencyModel(first.spec, first.params)
+                                if first.params is not None else None)
+                ctx = ExperimentContext(experiment=exp, results=results,
+                                        device=dev, backend=fres.backend)
+                metrics = exp.extract(ctx)
+                checks = tuple(exp.check(metrics))
+                out.append(ExperimentResult(
+                    experiment=exp, backend=fres.backend, metrics=metrics,
+                    checks=checks,
+                    n_requests=sum(len(r) for r in results.values()),
+                    converged=all(r.converged for r in results.values())))
+            return out
 
     # -- artifacts -----------------------------------------------------------
     def write_artifacts(self, results: Sequence[ExperimentResult],

@@ -90,11 +90,14 @@ def zns_fixpoint(comp0, svc, blocks, *, sweeps: int = 8,
     from :class:`repro.core.ChainProgram`.  ``adj`` is the symmetric
     block-adjacency matrix (``repro.core.chain_program.block_adjacency``)
     driving the in-kernel active-set mask; computed from the blocks when
-    omitted.  Returns ``(completions, sweeps_used, converged)``.
-    ``impl='xla'`` runs the jitted ``lax.while_loop`` form,
-    ``'pallas'``/``'interpret'`` the Pallas kernel (compiled / interpret
-    mode).  Computes in the dtype ``comp0`` has as a jax array: float64
-    under ``jax.enable_x64``, float32 otherwise.
+    omitted.  Returns ``(completions, sweeps_used, converged,
+    active_blocks)``: ``impl='xla'`` runs the jitted ``lax.while_loop``
+    form, whose ``active_blocks`` is an int32 vector of length
+    ``sweeps`` holding the blocks active in each sweep run (zeros past
+    ``sweeps_used``); ``'pallas'``/``'interpret'`` run the Pallas kernel
+    (compiled / interpret mode), which gives ``None`` there.  Computes
+    in the dtype ``comp0`` has as a jax array: float64 under
+    ``jax.enable_x64``, float32 otherwise.
     """
     from .zns_fixpoint import blocks_adjacency
     blocks = tuple((jnp.asarray(g, dtype=jnp.int32), jnp.asarray(h, bool))
@@ -107,4 +110,4 @@ def zns_fixpoint(comp0, svc, blocks, *, sweeps: int = 8,
     if impl == "xla":
         return _zns_fixpoint_xla(comp0, svc, blocks, adj, sweeps=int(sweeps))
     return _zns_fixpoint(comp0, svc, blocks, adj, sweeps=int(sweeps),
-                         interpret=(impl == "interpret"))
+                         interpret=(impl == "interpret")) + (None,)

@@ -139,8 +139,10 @@ def _fixpoint_core(comp_ext, svc_ext, blocks, sweeps: int, adj=None):
     converged block costs one predicate evaluation instead of a full
     gather + scan until a neighbour's scatter re-activates it; ``None``
     keeps every block active every sweep).  Returns ``(comp_ext,
-    sweeps_used, moved)`` where ``moved`` means "blocks still active at
-    exit" — its negation is the convergence flag.
+    sweeps_used, moved, active_counts)`` where ``moved`` means "blocks
+    still active at exit" — its negation is the convergence flag — and
+    ``active_counts[s]`` (int32, length ``sweeps``) the blocks active
+    when their turn came in sweep ``s`` (0 past ``sweeps_used``).
     """
 
     dead = comp_ext.shape[0] - 1
@@ -154,9 +156,10 @@ def _fixpoint_core(comp_ext, svc_ext, blocks, sweeps: int, adj=None):
     later_f = [jnp.arange(nf) > f for f in range(nf)]
 
     def body(carry):
-        comp, s, active = carry
+        comp, s, active, counts = carry
         act_now = active
         act_next = jnp.zeros_like(active)
+        n_active = jnp.int32(0)
         for f, (gidx, heads) in enumerate(blocks):
 
             def run(comp, gidx=gidx, heads=heads):
@@ -171,6 +174,7 @@ def _fixpoint_core(comp_ext, svc_ext, blocks, sweeps: int, adj=None):
                 comp = comp.at[-1].set(ninf)
                 return comp, mv
 
+            n_active = n_active + act_now[f].astype(jnp.int32)
             comp, mv = jax.lax.cond(
                 act_now[f], run, lambda c: (c, jnp.bool_(False)), comp)
             # a moving block re-activates neighbours: later blocks see
@@ -179,12 +183,13 @@ def _fixpoint_core(comp_ext, svc_ext, blocks, sweeps: int, adj=None):
             nbr = adj[f] & mv
             act_now = act_now | (nbr & later_f[f])
             act_next = act_next | (nbr & ~later_f[f])
-        return comp, s + 1, act_next
+        return comp, s + 1, act_next, counts.at[s].set(n_active)
 
-    comp, used, active = jax.lax.while_loop(
+    comp, used, active, counts = jax.lax.while_loop(
         lambda c: (c[1] < sweeps) & jnp.any(c[2]),
-        body, (comp_ext, jnp.int32(0), jnp.ones((max(nf, 1),), bool)))
-    return comp, used, jnp.any(active)
+        body, (comp_ext, jnp.int32(0), jnp.ones((max(nf, 1),), bool),
+               jnp.zeros((sweeps,), jnp.int32)))
+    return comp, used, jnp.any(active), counts
 
 
 def _extend(comp0, svc):
@@ -204,12 +209,13 @@ def zns_fixpoint_xla(comp0, svc, blocks, adj=None, *, sweeps: int = 8):
     bool (R, L))`` with padding indexed at ``n``; ``adj``: optional
     ``(F, F)`` bool block adjacency for the active-set mask.  Computes
     in the dtype of ``comp0``.  Returns ``(comp (n,), sweeps_used,
-    converged)``.
+    converged, active_blocks (sweeps,))``, the last the blocks active in
+    each sweep run.
     """
     comp_ext, svc_ext = _extend(comp0, svc)
-    comp, used, moved = _fixpoint_core(comp_ext, svc_ext, blocks, sweeps,
-                                       adj)
-    return comp[:-1], used, ~moved
+    comp, used, moved, counts = _fixpoint_core(comp_ext, svc_ext, blocks,
+                                               sweeps, adj)
+    return comp[:-1], used, ~moved, counts
 
 
 def _kernel(comp_ref, svc_ref, adj_ref, *rest, sweeps: int):
@@ -222,7 +228,7 @@ def _kernel(comp_ref, svc_ref, adj_ref, *rest, sweeps: int):
     block_refs, out_refs = rest[:-n_out], rest[-n_out:]
     blocks = tuple((block_refs[i][...], block_refs[i + 1][...])
                    for i in range(0, len(block_refs), 2))
-    comp, used, moved = _fixpoint_core(
+    comp, used, moved, _ = _fixpoint_core(
         comp_ref[...], svc_ref[...], blocks, sweeps, adj_ref[...])
     out_refs[0][...] = comp
     out_refs[1][...] = used[None]
@@ -275,7 +281,7 @@ def _stack_solve(comp0, svc, adj, *flat_blocks, sweeps: int):
     def one(args):
         c, v, a, *bl = args
         blocks = tuple((bl[i], bl[i + 1]) for i in range(0, len(bl), 2))
-        comp, used, moved = _fixpoint_core(c, v, blocks, sweeps, a)
+        comp, used, moved, _ = _fixpoint_core(c, v, blocks, sweeps, a)
         return comp, used, ~moved
 
     return jax.lax.map(one, (comp0, svc, adj) + tuple(flat_blocks))

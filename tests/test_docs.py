@@ -20,6 +20,7 @@ MD_FILES = sorted(p for p in REPO.glob("**/*.md")
 DOCTEST_MODULES = (
     "repro.core.arrival",
     "repro.core.chain_program",
+    "repro.core.spans",
     "repro.core.device",
     "repro.core.workload",
     "repro.core.latency",
