@@ -1296,37 +1296,18 @@ def block_adjacency(program: ChainProgram) -> np.ndarray:
     This is the dependency structure the active-set sweep driver uses to
     decide which converged blocks a moving block re-activates.  The
     diagonal is False: a block is at its own fixpoint immediately after
-    its scan, so it never re-activates itself.  Memoized on the program
-    (frozen but not slotted, same trick as the trace digest memo).
+    its scan, so it never re-activates itself.  Computed by
+    :func:`repro.kernels.zns_fixpoint.blocks_adjacency` under the span
+    ``solve.adjacency`` and memoized on the program (frozen but not
+    slotted, same trick as the trace digest memo).
     """
     cached = getattr(program, "_adjacency_memo", None)
     if cached is not None:
         return cached
-    nf = len(program.families)
-    adj = np.zeros((nf, nf), dtype=bool)
-    if nf > 1:
-        dead = program.n_flat
-        parts, owners = [], []
-        for f, blk in enumerate(program.families):
-            flat = blk.gidx.ravel()
-            flat = flat[flat != dead]
-            parts.append(flat)
-            owners.append(np.full(len(flat), f, dtype=np.int32))
-        idx = np.concatenate(parts)
-        own = np.concatenate(owners)
-        order = np.argsort(idx, kind="stable")
-        idx, own = idx[order], own[order]
-        # Runs of equal index mark every pair of owning blocks adjacent.
-        # An index appears at most once per block, so run length <= F and
-        # comparing each shift k < F covers all within-run pairs.
-        for k in range(1, nf):
-            same = idx[k:] == idx[:-k]
-            if not same.any():
-                break
-            a, b = own[k:][same], own[:-k][same]
-            adj[a, b] = True
-            adj[b, a] = True
-        np.fill_diagonal(adj, False)
+    from repro.kernels.zns_fixpoint import blocks_adjacency
+    with spans.span("solve.adjacency"):
+        adj = blocks_adjacency([blk.gidx for blk in program.families],
+                               program.n_flat)
     try:
         object.__setattr__(program, "_adjacency_memo", adj)
     except Exception:        # pragma: no cover - slotted subclass

@@ -38,6 +38,7 @@ NAMES = (
     "lower.replay",             # greedy pool replay (both rebuilds)
     "lower.assemble",           # family lists + fleet block fill
     "solve.prepare",            # adjacency, initial vector, device puts
+    "solve.adjacency",          # block adjacency (memo misses only)
     "solve.wait",               # until the device's result is ready
     "solve.fetch",              # device -> host copy of the result
     "fleet.unpack",             # flat solve -> per-device results

@@ -104,29 +104,29 @@ def blocks_adjacency(gidxs, n: int) -> np.ndarray:
     matrices: ``adj[i, j]`` iff blocks ``i`` and ``j`` address a common
     flat slot (padding at ``n`` excluded).  Diagonal False — a block is
     at its own fixpoint right after its scan.  Host-side numpy; the
-    kernels consume the result as a traced bool array."""
+    kernels consume the result as a traced bool array.
+
+    One linear pass per word of up to 64 blocks: each block of the word
+    stamps its bit into a per-slot word of the narrowest unsigned type
+    that holds the word's blocks (the padding slot cleared after), then
+    every block OR-reduces the stamps it gathers.  The cost is
+    ``ceil(F / 64)`` times the lanes of all blocks, whatever ``n``."""
     nf = len(gidxs)
     adj = np.zeros((nf, nf), dtype=bool)
-    if nf > 1:
-        parts, owners = [], []
-        for f, g in enumerate(gidxs):
-            flat = np.asarray(g).ravel()
-            flat = flat[flat != n]
-            parts.append(flat)
-            owners.append(np.full(len(flat), f, dtype=np.int32))
-        idx = np.concatenate(parts)
-        own = np.concatenate(owners)
-        order = np.argsort(idx, kind="stable")
-        idx, own = idx[order], own[order]
-        # an index appears at most once per block, so runs of equal
-        # index are <= F long; shifted compares cover all in-run pairs
-        for k in range(1, nf):
-            same = idx[k:] == idx[:-k]
-            if not same.any():
-                break
-            adj[own[k:][same], own[:-k][same]] = True
-            adj[own[:-k][same], own[k:][same]] = True
-        np.fill_diagonal(adj, False)
+    if nf < 2:
+        return adj
+    flats = [np.asarray(g).ravel() for g in gidxs]
+    for lo in range(0, nf, 64):
+        hi = min(lo + 64, nf)
+        word = np.min_scalar_type((1 << (hi - lo)) - 1).type
+        bits = np.zeros(n + 1, dtype=word)
+        for k in range(hi - lo):
+            bits[flats[lo + k]] |= word(1 << k)
+        bits[n] = 0
+        for f, flat in enumerate(flats):
+            m = int(np.bitwise_or.reduce(bits[flat]))
+            adj[f, lo:hi] = [(m >> k) & 1 for k in range(hi - lo)]
+    np.fill_diagonal(adj, False)
     return adj
 
 

@@ -5,7 +5,8 @@
 * the program cache's counters live in the table, and
   ``program_cache_info()`` keeps its shape;
 * a small fleet query with the ``"xla"`` driver, plus the observation
-  runner's ``evaluate``, opens every span in ``spans.NAMES``;
+  runner's ``evaluate``, opens every span in ``spans.NAMES``, the block
+  adjacency's inside the solve's preparation;
 * under the JAX profiler, the program's spans land in the trace on the
   same host clock as an enclosing ``TraceAnnotation``.
 """
@@ -125,6 +126,9 @@ def test_a_fleet_query_opens_every_span():
     assert snap["lower"]["total"] >= sum(
         snap[k]["total"] for k in ("lower.digest", "lower.devices",
                                    "lower.replay", "lower.assemble"))
+    # the XLA driver computes the block adjacency inside its preparation
+    assert "solve.adjacency" in snap
+    assert snap["solve.prepare"]["total"] >= snap["solve.adjacency"]["total"]
 
 
 def _host_events(path, names):
