@@ -20,6 +20,11 @@ import numpy as np
 from entries import common
 
 
+def shrink(traffic):
+    """Unchanged: the whole registry already runs in about a second."""
+    return traffic
+
+
 class Cell:
     def __init__(self, traffic, config, seed):
         from repro.experiments import ExperimentRunner

@@ -16,6 +16,15 @@ import numpy as np
 from entries import common
 
 
+def shrink(traffic):
+    """4 devices, all of them checked, and at most 1,000 requests a
+    stream."""
+    t = dict(traffic, devices=4, check_devices=4)
+    t["mixes"] = [[dict(s, n=min(s["n"], 1000)) for s in mix]
+                  for mix in traffic["mixes"]]
+    return t
+
+
 class Cell:
     def __init__(self, traffic, config, seed):
         from repro.core import DeviceFleet, WorkloadSpec

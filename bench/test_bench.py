@@ -9,11 +9,17 @@
   comparison, at a small size;
 * the harness after its look for a chip, with the timed path broken
   underneath in each way a cell can break, reports ``correct`` false,
-  and true when nothing is broken.
+  and true when nothing is broken; a broken solver function is bound
+  under every name a ``repro`` module gives it;
+* each entry's rehearsal size: its own ``shrink``, one query of at
+  most 250,000 events.
 """
+import functools
+import importlib
 import json
 import subprocess
 import sys
+import types
 from pathlib import Path
 
 import numpy as np
@@ -164,22 +170,155 @@ def _altered(unpack):
     return broken
 
 
-FAULTS = {"unchanged": ("solve_program", _unchanged),
-          "half_left_out": ("solve_program", _half),
-          "answer_altered": ("unpack_results", _altered)}
+def _kept_altered(keep, calls):
+    """``Cell.keep`` with the last value of each kept answer raised by
+    1.0; ``calls`` grows by one each time it alters an answer."""
+    def broken(self, *a, **kw):
+        out = []
+        for key, got in keep(self, *a, **kw):
+            got = np.array(got, dtype=np.float64)
+            if got.size:
+                got.flat[-1] += 1.0
+                calls.append(type(self).__module__)
+            out.append((key, got))
+        return out
+    return broken
+
+
+# fault -> (function of repro.core.chain_program it breaks, breaker);
+# ``kept_answer_altered`` breaks the entry's own ``Cell.keep`` instead
+SOLVER_FAULTS = {"unchanged": ("solve_program", _unchanged),
+                 "half_left_out": ("solve_program", _half),
+                 "answer_altered": ("unpack_results", _altered)}
+FAULTS = [*SOLVER_FAULTS, "kept_answer_altered"]
+# the packages whose modules may bind a solver function by name: every
+# one is imported before a fault is planted, so none binds it later
+SOLVER_USERS = ("repro.core", "repro.experiments", "repro.cluster")
+
+
+def _entry(name):
+    import run
+
+    return run.load_cell(name)[3]["entry"]
+
+
+def _must_reach(fault, entry):
+    """Every cell's timed path solves and keeps answers; only the fleet
+    and experiments entries unpack with ``unpack_results`` (the cluster
+    path unpacks with ``op_latencies``)."""
+    return fault != "answer_altered" or entry in ("fleet", "experiments")
+
+
+def _bind_everywhere(monkeypatch, original, replacement):
+    """Bind ``replacement`` under every name a loaded ``repro`` module
+    gives ``original``; the names bound."""
+    names = []
+    for mod_name, mod in list(sys.modules.items()):
+        if mod_name != "repro" and not mod_name.startswith("repro."):
+            continue
+        for attr, value in list(vars(mod).items()):
+            if value is original:
+                monkeypatch.setattr(mod, attr, replacement)
+                names.append(f"{mod_name}.{attr}")
+    return names
+
+
+def _plant(fault, entry, monkeypatch):
+    """Break the timed path by ``fault``; a list that grows by the
+    calling module's name each time the broken code runs."""
+    calls = []
+    if fault == "kept_answer_altered":
+        cell = importlib.import_module(f"entries.{entry}").Cell
+        monkeypatch.setattr(cell, "keep", _kept_altered(cell.keep, calls))
+        return calls
+    for package in SOLVER_USERS:
+        importlib.import_module(package)
+    from repro.core import chain_program
+
+    attr, breaker = SOLVER_FAULTS[fault]
+    original = getattr(chain_program, attr)
+    broken = breaker(original)
+
+    @functools.wraps(original)
+    def counted(*a, **kw):
+        calls.append(sys._getframe(1).f_globals.get("__name__"))
+        return broken(*a, **kw)
+
+    names = _bind_everywhere(monkeypatch, original, counted)
+    assert f"repro.core.chain_program.{attr}" in names, names
+    return calls
 
 
 @pytest.mark.parametrize("fault", [None, *FAULTS])
 @pytest.mark.parametrize("name", CELLS)
 def test_harness_sees_broken_timed_path(name, fault, monkeypatch):
-    from repro.core import chain_program
-
-    if fault is not None:
-        attr, breaker = FAULTS[fault]
-        monkeypatch.setattr(chain_program, attr,
-                            breaker(getattr(chain_program, attr)))
+    entry = _entry(name)
+    calls = _plant(fault, entry, monkeypatch) if fault else []
     out, numbers, limits = rehearse.rehearse(name)
-    assert out["correct"] is (fault is None), numbers
+    if fault is None:
+        assert out["correct"] is True, numbers
+    elif calls:
+        assert out["correct"] is False, (name, fault, numbers)
+    else:
+        assert not _must_reach(fault, entry), \
+            f"the fault {fault} never reached the timed path of {name}"
+        # a fault the path does not reach leaves the run sound
+        assert out["correct"] is True, (name, fault, numbers)
+
+
+def test_solver_fault_reaches_cluster_binding(monkeypatch):
+    """``repro.cluster.compiler`` binds ``solve_program`` by name at
+    import; a planted fault reaches that binding too."""
+    from repro.cluster import Cluster, ClusterSpec, ClusterWorkload, erasure
+
+    rack = Cluster(ClusterSpec(n_gateways=1, n_servers=4,
+                               scheme=erasure(2, 1)))
+    workload = ClusterWorkload(n_users=4, ops_per_user=4)
+    sound = np.asarray(rack.compile(workload).comp)
+    calls = _plant("unchanged", None, monkeypatch)
+    broken = np.asarray(rack.compile(workload).comp)
+    assert "repro.cluster.compiler" in calls, calls
+    assert broken.shape == sound.shape and not np.array_equal(broken, sound)
+
+
+def test_fleet_shrink_keeps_the_rule():
+    import run
+    from entries import fleet
+
+    traffic = run.load_cell("zn540.fleet64-randrw4k")[3]
+    assert fleet.shrink(traffic) == {
+        "entry": "fleet", "devices": 4, "jitter": False, "variants": 2,
+        "check_devices": 4, "limits": {"max_rel_err": 1e-09},
+        "mixes": [[
+            {"op": "WRITE", "n": 1000, "size": 4096, "qd": 4, "nzones": 64},
+            {"op": "READ", "n": 1000, "size": 4096, "qd": 16,
+             "nzones": 64}]]}
+    assert traffic["devices"] == 64
+    assert traffic["mixes"][0][0]["n"] == 50000
+
+
+def test_experiments_shrink_is_identity():
+    import run
+    from entries import experiments
+
+    traffic = run.load_cell("zn540.paper-matrix")[3]
+    assert experiments.shrink(traffic) == traffic
+    assert rehearse.shrink(traffic) == traffic
+
+
+def test_rehearse_refuses_entry_without_shrink(monkeypatch):
+    entry = types.ModuleType("entries.noshrink")
+    entry.Cell = type("Cell", (), {})
+    monkeypatch.setitem(sys.modules, "entries.noshrink", entry)
+    with pytest.raises(ValueError, match="noshrink"):
+        rehearse.shrink({"entry": "noshrink", "n": 10**9})
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_rehearsal_query_is_small(name):
+    """No cell puts a full-size query into the CPU self-checks."""
+    work, _ = _cell(name)
+    assert 0 < work.events(work.run(work.variants[0])) <= 250_000
 
 
 def test_run_refuses_without_tpu():
