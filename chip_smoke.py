@@ -21,7 +21,8 @@ Phases on one chip:
   with 50k 4 KiB writes at qd 4 and 50k 4 KiB reads at qd 16 over 64
   zones, jittered, seed ``i`` on device ``i``: 6.4M requests.
 * ``rack`` — ``Cluster(ClusterSpec(scheme=erasure(4, 2)))`` running the
-  default ``ClusterWorkload``.
+  default ``ClusterWorkload`` (its pop-order refinement runs on the
+  host under ``auto``; the final solve on the chip).
 
 With ``--chips 4``: the mega-fleet benchmark's straggler fleet solved by
 the ``shard_map`` mesh executor across the four chips, compared with
@@ -184,13 +185,14 @@ def rack_phase(clock, expect):
     rec["objects"] = res.n_ops
     rec["refine_iters"] = res.compiled.program.refine_used
     rec["order_stable"] = bool(res.compiled.program.order_stable)
-    # Recompile warm-started from the device's result.  The compiler
+    # Recompile warm-started from the device's result, its refinement
+    # solved on the chip (``auto`` refines on the host).  The compiler
     # keeps a warm seed only when its tightness check (rtol 1e-12)
     # accepts the solve, so a device answer that is off by more than
     # float64 rounding shows up here as a rejection the host does not make.
     accepted = {who: cluster.compile(wl, fixpoint=fp,
                                      comp0=res.comp).warm_start_used
-                for who, fp in (("host", "loop"), ("device", "auto"))}
+                for who, fp in (("host", "loop"), ("device", "xla"))}
     rec["warm_seed_accepted"] = accepted
     if accepted["device"] != accepted["host"]:
         rec["problems"].append(f"warm-start verification differs: "

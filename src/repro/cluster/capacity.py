@@ -28,7 +28,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import PoissonArrivals, concat_programs, solve_program
+from repro.core import PoissonArrivals, concat_programs, solve_program, \
+    spans
 from repro.core.metrics import DEFAULT_SLO_US, LatencyStats, violation_rate
 
 from .cluster import Cluster
@@ -121,6 +122,8 @@ class CapacityPoint:
 
     ``offered_rate`` is the open-loop arrival rate (objects/s) of a
     ``rate_ladder`` rung; ``None`` on closed-loop (users-ladder) points.
+    ``latencies`` holds every op's latency (us, in op ``seq`` order),
+    the samples ``lat`` and the violation rate were drawn from.
     """
 
     users: int
@@ -129,6 +132,8 @@ class CapacityPoint:
     slo_violation_rate: float
     converged: bool
     offered_rate: Optional[float] = None
+    latencies: Optional[np.ndarray] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     def to_json(self) -> Dict[str, float]:
         out = {"users": self.users,
@@ -261,46 +266,14 @@ def _can_degrade(scheme: RedundancyScheme) -> bool:
     return scheme.m >= 1
 
 
-def plan_capacity(configs: Sequence[ClusterConfig],
-                  users_ladder: Sequence[int], *,
-                  base_spec: Optional[ClusterSpec] = None,
-                  workload: Optional[ClusterWorkload] = None,
-                  slo_us: float = DEFAULT_SLO_US,
-                  rate_ladder: Optional[Sequence[float]] = None,
-                  degraded: bool = True, down_server: int = 0,
-                  sweeps: int = 512, fixpoint: str = "auto",
-                  scan_backend: str = "auto",
-                  max_refine: Optional[int] = None,
-                  warm_ladder: bool = False) -> CapacityReport:
-    """Compile the whole sweep, solve it as ONE fleet-level program,
-    and slice the capacity curves back out.
-
-    ``rate_ladder`` switches the sweep to open-loop offered load: each
-    rung keeps the workload's user population but stamps Poisson
-    arrivals at that rate (objects/s, ``qd`` raised to ``ops_per_user``
-    so the closed-loop edges vanish), ``users_ladder`` is ignored, and
-    curves rank by :func:`rate_at_slo` instead of :func:`users_at_slo`.
-
-    ``warm_ladder=True`` threads each rung's completions into the next
-    rung's refined solves as ``comp0`` (ops joined per ``(client,
-    slot)`` key when their content digests match), seeds the FIFO
-    pop-order refinement from the previous rung's orders, and — on
-    rate ladders, whose rungs share their entire structure — reuses
-    the previous rung's graph with the new arrival clock re-stamped
-    instead of rebuilding placement and shard planning from scratch.
-    Rung monotonicity is not assumed: the warm solve only sticks when
-    the tightness verification proves it equal to the cold result (see
-    :func:`repro.cluster.compiler.compile_graph`), so the report is
-    identical either way — ``warm_hits`` / ``warm_attempts`` expose
-    how often the shortcut landed.  Rate ladders pay best (graph reuse
-    plus order carry-over); users ladders rebuild each rung's graph
-    and warm only the solves.
-    """
-    base_spec = base_spec if base_spec is not None else ClusterSpec()
-    workload = workload if workload is not None else ClusterWorkload()
-    open_loop = rate_ladder is not None
-    rungs = [float(r) for r in rate_ladder] if open_loop \
-        else [int(u) for u in users_ladder]
+def _compile_sweep(configs: Sequence[ClusterConfig], rungs: Sequence,
+                   base_spec: ClusterSpec, workload: ClusterWorkload, *,
+                   open_loop: bool, degraded: bool, down_server: int,
+                   sweeps: int, fixpoint: str, scan_backend: str,
+                   max_refine: Optional[int], warm_ladder: bool):
+    """Compile every config x mode x rung of the sweep to its own
+    program: ``(entries, warm_hits, warm_attempts)``, each entry
+    ``(config, degraded, users, offered rate, compiled)``."""
     entries: List[Tuple[ClusterConfig, bool, int, Optional[float],
                         CompiledCluster]] = []
     warm_hits = warm_attempts = 0
@@ -367,18 +340,13 @@ def plan_capacity(configs: Sequence[ClusterConfig],
                         scan_backend=scan_backend, **kw)
                 entries.append((cfg, down is not None, users, rate,
                                 compiled))
+    return entries, warm_hits, warm_attempts
 
-    # ONE fleet-level call over every config x rung x mode.  The
-    # per-entry fixpoints found during compilation are exact lower
-    # bounds of the concatenated program, so they seed the fleet solve
-    # (comp0) and it converges in one verification sweep.
-    program = concat_programs([c.program for *_, c in entries])
-    svc = np.concatenate([c.graph.svc for *_, c in entries])
-    comp, used, converged = solve_program(
-        program, svc, sweeps=sweeps, fixpoint=fixpoint,
-        scan_backend=scan_backend, warn=False,
-        comp0=np.concatenate([c.comp for *_, c in entries]))
 
+def _slice_curves(entries, comp: np.ndarray, converged: bool,
+                  slo_us: float) -> List[CapacityCurve]:
+    """Each entry's completions out of the one fleet solve: its per-op
+    latencies, as one point of its (config, mode) curve."""
     curves: List[CapacityCurve] = []
     off = 0
     by_key: Dict[Tuple[str, bool], List[CapacityPoint]] = {}
@@ -395,7 +363,7 @@ def plan_capacity(configs: Sequence[ClusterConfig],
             lat=LatencyStats.from_samples(lats),
             slo_violation_rate=violation_rate(lats, slo_us),
             converged=bool(converged and compiled.converged),
-            offered_rate=rate)
+            offered_rate=rate, latencies=lats)
         key = (cfg.name, is_degraded)
         by_key.setdefault(key, []).append(point)
         key_cfg[key] = cfg
@@ -406,13 +374,78 @@ def plan_capacity(configs: Sequence[ClusterConfig],
             config=key_cfg[key], degraded=key[1], points=tuple(points),
             users_at_slo=users_at_slo(points, slo_us),
             rate_at_slo=rate_at_slo(points, slo_us)))
-    unstable = tuple(sorted({
-        cfg.name for cfg, *_, c in entries
-        if not c.program.order_stable}))
-    return CapacityReport(
-        curves=curves, slo_us=slo_us, n_programs=len(entries),
-        n_events=program.n_flat, sweeps_used=used,
-        converged=bool(converged) and all(
-            c.converged for *_, c in entries),
-        order_unstable=unstable,
-        warm_hits=int(warm_hits), warm_attempts=int(warm_attempts))
+    return curves
+
+
+def plan_capacity(configs: Sequence[ClusterConfig],
+                  users_ladder: Sequence[int], *,
+                  base_spec: Optional[ClusterSpec] = None,
+                  workload: Optional[ClusterWorkload] = None,
+                  slo_us: float = DEFAULT_SLO_US,
+                  rate_ladder: Optional[Sequence[float]] = None,
+                  degraded: bool = True, down_server: int = 0,
+                  sweeps: int = 512, fixpoint: str = "auto",
+                  scan_backend: str = "auto",
+                  max_refine: Optional[int] = None,
+                  warm_ladder: bool = False) -> CapacityReport:
+    """Compile the whole sweep, solve it as ONE fleet-level program,
+    and slice the capacity curves back out.
+
+    ``rate_ladder`` switches the sweep to open-loop offered load: each
+    rung keeps the workload's user population but stamps Poisson
+    arrivals at that rate (objects/s, ``qd`` raised to ``ops_per_user``
+    so the closed-loop edges vanish), ``users_ladder`` is ignored, and
+    curves rank by :func:`rate_at_slo` instead of :func:`users_at_slo`.
+
+    ``warm_ladder=True`` threads each rung's completions into the next
+    rung's refined solves as ``comp0`` (ops joined per ``(client,
+    slot)`` key when their content digests match), seeds the FIFO
+    pop-order refinement from the previous rung's orders, and — on
+    rate ladders, whose rungs share their entire structure — reuses
+    the previous rung's graph with the new arrival clock re-stamped
+    instead of rebuilding placement and shard planning from scratch.
+    Rung monotonicity is not assumed: the warm solve only sticks when
+    the tightness verification proves it equal to the cold result (see
+    :func:`repro.cluster.compiler.compile_graph`), so the report is
+    identical either way — ``warm_hits`` / ``warm_attempts`` expose
+    how often the shortcut landed.  Rate ladders pay best (graph reuse
+    plus order carry-over); users ladders rebuild each rung's graph
+    and warm only the solves.
+    """
+    with spans.span("cluster.plan"):
+        base_spec = base_spec if base_spec is not None else ClusterSpec()
+        workload = workload if workload is not None else ClusterWorkload()
+        open_loop = rate_ladder is not None
+        rungs = [float(r) for r in rate_ladder] if open_loop \
+            else [int(u) for u in users_ladder]
+        entries, warm_hits, warm_attempts = _compile_sweep(
+            configs, rungs, base_spec, workload, open_loop=open_loop,
+            degraded=degraded, down_server=down_server, sweeps=sweeps,
+            fixpoint=fixpoint, scan_backend=scan_backend,
+            max_refine=max_refine, warm_ladder=warm_ladder)
+
+        # ONE fleet-level call over every config x rung x mode.  The
+        # per-entry fixpoints found during compilation are exact lower
+        # bounds of the concatenated program, so they seed the fleet solve
+        # (comp0) and it converges in one verification sweep.
+        program = concat_programs([c.program for *_, c in entries])
+        svc = np.concatenate([c.graph.svc for *_, c in entries])
+        comp, used, converged = solve_program(
+            program, svc, sweeps=sweeps, fixpoint=fixpoint,
+            scan_backend=scan_backend, warn=False,
+            comp0=np.concatenate([c.comp for *_, c in entries]))
+        with spans.span("cluster.slice"):
+            curves = _slice_curves(entries, comp, converged, slo_us)
+        spans.count("cluster.warm_hits", warm_hits)
+        spans.count("cluster.warm_attempts", warm_attempts)
+
+        unstable = tuple(sorted({
+            cfg.name for cfg, *_, c in entries
+            if not c.program.order_stable}))
+        return CapacityReport(
+            curves=curves, slo_us=slo_us, n_programs=len(entries),
+            n_events=program.n_flat, sweeps_used=used,
+            converged=bool(converged) and all(
+                c.converged for *_, c in entries),
+            order_unstable=unstable,
+            warm_hits=int(warm_hits), warm_attempts=int(warm_attempts))

@@ -37,7 +37,8 @@ The compiled per-config programs are pure data: the capacity planner
 concatenates dozens of them (:func:`repro.core.concat_programs`) and
 solves the whole rack sweep in ONE :func:`repro.core.solve_program`
 call — on the chip's float64 XLA fixpoint on a TPU
-(:mod:`repro.core.platform` chooses).
+(:mod:`repro.core.platform` chooses).  The refinement's own solves
+decide pop orders and run in the host's float64 (``compile_graph``).
 """
 from __future__ import annotations
 
@@ -48,7 +49,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import ChainProgram, build_program, solve_program
+from repro.core import ChainProgram, build_program, solve_program, spans
 
 from .gateway import OpPlan, plan_workload
 from .server import StorageServer
@@ -93,7 +94,7 @@ class ClusterGraph:
     issue: np.ndarray                   # (n,) earliest event issue (us)
     svc: np.ndarray                     # (n,) jitter-free service (us)
     labels: List[str]                   # per-event stage tag (debug)
-    paths: List[Tuple[str, List[List[int]]]]   # flow families
+    paths: List[Tuple[str, List[List[int]]]]   # flow + client order
     edges: List[Tuple[str, int, int]]   # gate edges (name, pred, succ)
     resources: List[Resource]
     op_head: np.ndarray                 # (n_ops,) first event per op
@@ -164,6 +165,14 @@ def build_graph(spec: ClusterSpec, ops: Sequence[ObjectOp], *, qd: int = 1,
     ``qd`` is the clients' closed-loop depth: op ``i`` of a client is
     gated on the ack (join) of its op ``i - qd``.
     """
+    with spans.span("cluster.graph"):
+        return _build_graph(spec, ops, qd=qd, down=down, seed=seed,
+                            plans=plans)
+
+
+def _build_graph(spec: ClusterSpec, ops: Sequence[ObjectOp], *, qd: int,
+                 down: Optional[int], seed: int,
+                 plans: Optional[List[OpPlan]]) -> ClusterGraph:
     if plans is None:
         plans = plan_workload(spec, ops, seed=seed, down=down)
     net, gw, srv = spec.network, spec.gateway, spec.server
@@ -261,14 +270,14 @@ def build_graph(spec: ClusterSpec, ops: Sequence[ObjectOp], *, qd: int = 1,
     # Closed loop: client op i waits for the ack of its op i - qd, and
     # clients prepare requests in program order (op i's gateway stage
     # follows op i-1's) — together these give read-your-writes at any
-    # queue depth.
+    # queue depth.  The program order is one chain per client.
     per_client: Dict[int, List[int]] = {}
     for op in ops:
         per_client.setdefault(op.client, []).append(op.seq)
     for seqs in per_client.values():
-        for i in range(1, len(seqs)):
-            b.edges.append(("seq", int(op_head[seqs[i - 1]]),
-                            int(op_head[seqs[i]])))
+        if len(seqs) > 1:
+            b.paths.setdefault("seq", []).append(
+                [int(op_head[s]) for s in seqs])
         for i in range(qd, len(seqs)):
             b.edges.append(("closed", int(op_tail[seqs[i - qd]]),
                             int(op_head[seqs[i]])))
@@ -323,6 +332,18 @@ def edge_families(edges: Sequence[Tuple[str, int, int]]
         fams.setdefault(f"{name}/{o}", []).append(
             np.asarray([a, b], dtype=np.int64))
         occ[(name, a)] = occ[(name, b)] = o + 1
+    return sorted(fams.items())
+
+
+def _pooled(pools) -> List[Tuple[str, List[np.ndarray]]]:
+    """``(label, chains)`` pairs of pools merged into one family per pool
+    kind (``srv_cpu`` of ``srv_cpu/r3``).  Pools of one kind hold
+    disjoint events, so every server's chains can share a family, and
+    the program has one block per length bucket of the kind rather than
+    one per server."""
+    fams: Dict[str, List[np.ndarray]] = {}
+    for label, chains in pools:
+        fams.setdefault(label.split("/")[0], []).extend(chains)
     return sorted(fams.items())
 
 
@@ -512,21 +533,34 @@ def compile_graph(graph: ClusterGraph, *, sweeps: int = 512,
     replay loop takes over.  Like ``order_seed`` it biases only the
     trajectory: the accepted program still has to replay its own
     chains verbatim.
+
+    ``fixpoint`` names how the refinement's solves run.  ``auto``
+    is the host's float64 ``loop`` on every platform, because the pop
+    orders those solves decide are compared on a 1e-6 us grid.  An
+    order that another fixpoint settles on is solved once more on the
+    host, and refinement goes on there if it moves.
     """
+    # In an accelerator's float64 a tie near a grid line can fall on
+    # either side of it and flip a queue: pop orders settle on the host.
+    if fixpoint == "auto":
+        fixpoint = "loop"
+    host = fixpoint == "loop"
     static: List[Tuple[str, List[np.ndarray]]] = []
     for label, chains in graph.paths:
         static.append((label, [np.asarray(c, dtype=np.int64)
                                for c in chains]))
     static.extend(edge_families(graph.edges))
     fifo_res: List[Resource] = []
+    ordered = []
     for res in graph.resources:
         if len(res.members) <= res.cap:
             continue                       # never queues: no chain needed
         if res.ordered:
-            static.append((res.label, _lag_chains(
+            ordered.append((res.label, _lag_chains(
                 np.asarray(res.members, dtype=np.int64), res.cap)))
         else:
             fifo_res.append(res)
+    static.extend(_pooled(ordered))
     # Service-class metadata (diagnostics only: the greedy replay is
     # exact for any mix once the chains freeze).
     multiclass = tuple(sorted(
@@ -540,7 +574,8 @@ def compile_graph(graph: ClusterGraph, *, sweeps: int = 512,
     # completions order events by pure dependency depth.  Starting the
     # FIFO chains from index order instead can thread a chain against
     # the DAG and make the first refinement solve cyclic (divergent).
-    base = build_program(graph.issue, graph.svc, static)
+    with spans.span("cluster.rebuild"):
+        base = build_program(graph.issue, graph.svc, static)
     cand = None if comp0 is None else np.array(comp0, dtype=np.float64)
     warm_used = False
     comp, used, converged = solve_program(
@@ -560,24 +595,39 @@ def compile_graph(graph: ClusterGraph, *, sweeps: int = 512,
     prev_chains: Optional[List[List[np.ndarray]]] = None
     program: ChainProgram = base
     refine_used, order_stable = 0, not fifo_res
-    for it in range(max_refine + 1):
-        if it == 0 and chains0 is not None and len(chains0) == len(fifo_res):
-            rchains = [[np.asarray(c, dtype=np.int64) for c in ch]
-                       for ch in chains0]
-        else:
-            rchains = [_fifo_replay_chains(r, graph, ready)
-                       for r in fifo_res]
-        if prev_chains is not None and \
-                all(_chains_equal(a, p)
-                    for a, p in zip(rchains, prev_chains)):
+    it = 0
+    while it <= max_refine:
+        with spans.span("cluster.replay"):
+            if it == 0 and chains0 is not None and \
+                    len(chains0) == len(fifo_res):
+                rchains = [[np.asarray(c, dtype=np.int64) for c in ch]
+                           for ch in chains0]
+            else:
+                rchains = [_fifo_replay_chains(r, graph, ready)
+                           for r in fifo_res]
+            stable = prev_chains is not None and all(
+                _chains_equal(a, p) for a, p in zip(rchains, prev_chains))
+        if stable and host:
             order_stable = True
             break
-        fams = list(static)
-        for r, ch in zip(fifo_res, rchains):
-            fams.append((r.label, ch))
-        program = build_program(
-            graph.issue, graph.svc, fams,
-            exact=False, multiclass_pools=multiclass)
+        if stable:
+            with spans.span("cluster.confirm"):
+                fixpoint, host = "loop", True
+                boot_comp, _, _ = solve_program(
+                    base, graph.svc, sweeps=sweeps, fixpoint=fixpoint,
+                    scan_backend=scan_backend, warn=False)
+                comp, used, converged = solve_program(
+                    program, graph.svc, sweeps=sweeps, fixpoint=fixpoint,
+                    scan_backend=scan_backend, warn=False,
+                    comp0=boot_comp)
+                ready = _graph_ready(graph, dag, comp)
+            continue
+        fams = static + _pooled(
+            (r.label, ch) for r, ch in zip(fifo_res, rchains))
+        with spans.span("cluster.rebuild"):
+            program = build_program(
+                graph.issue, graph.svc, fams,
+                exact=False, multiclass_pools=multiclass)
         if cand is None:
             comp, used, converged = solve_program(
                 program, graph.svc, sweeps=sweeps, fixpoint=fixpoint,
@@ -587,14 +637,17 @@ def compile_graph(graph: ClusterGraph, *, sweeps: int = 512,
                 program, graph, boot_comp, cand, sweeps=sweeps,
                 fixpoint=fixpoint, scan_backend=scan_backend)
             warm_used = warm_used or ok
-        refine_used = it + 1
+        it += 1
+        refine_used = it
         ready = _graph_ready(graph, dag, comp)
         prev_chains = rchains
+    spans.count("cluster.refine_iters", refine_used)
     unstable: List[str] = []
     if not order_stable:
         # Budget exhausted: report which FIFO pools are still flapping
         # instead of silently downgrading the program to ``exact=False``.
-        nxt = [_fifo_replay_chains(r, graph, ready) for r in fifo_res]
+        with spans.span("cluster.replay"):
+            nxt = [_fifo_replay_chains(r, graph, ready) for r in fifo_res]
         unstable = [r.label for r, a, p in
                     zip(fifo_res, nxt, prev_chains or nxt)
                     if not _chains_equal(a, p)] or \

@@ -13,8 +13,9 @@ and the event-engine oracle both consume:
   flushed (:meth:`room_gate`);
 * **device** — flush chunks land in the server's
   :class:`repro.host.LogStructuredVolume` (zone allocation, open-zone
-  limits and capacity enforced live by the host layer); service times
-  come from the volume device's calibrated latency model, jitter-free.
+  limits and capacity enforced live by the host layer) on the drive
+  that ``ClusterSpec.device_spec`` and ``device_params`` describe;
+  service times come from that drive's latency model, jitter-free.
 
 The server never schedules anything itself — it answers the structural
 questions ("which flush covers byte ``hi``?", "how many chunks must
@@ -27,7 +28,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core import OpType, Trace, compute_service_times
+from repro.core import (LatencyModel, OpType, Trace, ZnsDevice,
+                        compute_service_times)
 from repro.host import LogStructuredVolume
 
 from .spec import ClusterSpec
@@ -39,8 +41,10 @@ class StorageServer:
     def __init__(self, sid: int, spec: ClusterSpec):
         self.sid = sid
         self.spec = spec
+        drive = ZnsDevice(spec.device_spec, lat=LatencyModel(
+            spec.device_spec, spec.device_params))
         self.volume = LogStructuredVolume(
-            spec.device_spec, policy="greedy-open",
+            device=drive, policy="greedy-open",
             stripe_bytes=spec.server.flush_chunk,
             append_qd=spec.server.flush_qd)
         self.cum = 0                              # bytes inserted so far

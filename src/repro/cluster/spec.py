@@ -25,7 +25,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from repro.core import ArrivalProcess, KiB, MiB, ZNSDeviceSpec
+from repro.core import (ArrivalProcess, DEFAULT_LATENCY_PARAMS, KiB,
+                        LatencyParams, MiB, ZNSDeviceSpec)
 
 from .codec import RedundancyScheme, erasure
 
@@ -85,6 +86,10 @@ class ServerSpec:
 class ClusterSpec:
     """One rack: gateways, servers, the fabric, and the redundancy plan.
 
+    ``device_spec`` and ``device_params`` are every server's drive: its
+    geometry and limits, and the calibrated latency coefficients its
+    flush appends and shard reads are timed with.
+
     ``durability`` selects the PUT acknowledgement point:
     ``"writeback"`` acks once the shard is in the server's buffer (the
     flush to flash is asynchronous but still backpressures through the
@@ -100,6 +105,7 @@ class ClusterSpec:
     gateway: GatewaySpec = GatewaySpec()
     server: ServerSpec = ServerSpec()
     device_spec: ZNSDeviceSpec = CLUSTER_DEVICE_SPEC
+    device_params: LatencyParams = DEFAULT_LATENCY_PARAMS
     durability: str = "writeback"
 
     def __post_init__(self):

@@ -43,6 +43,12 @@ NAMES = (
     "solve.fetch",              # device -> host copy of the result
     "fleet.unpack",             # flat solve -> per-device results
     "experiments.evaluate",     # ExperimentRunner.evaluate
+    "cluster.plan",             # plan_capacity, the whole call
+    "cluster.graph",            # build_graph: placement, shards, server logs
+    "cluster.replay",           # FIFO pop-order replays and their comparison
+    "cluster.rebuild",          # build_program inside compile_graph
+    "cluster.confirm",          # an off-host pop order, re-solved on the host
+    "cluster.slice",            # per-op latencies and capacity curves
 )
 
 #: Every counter the program keeps.
@@ -52,6 +58,9 @@ COUNTERS = (
     "program_cache.disk_hits",  # programs loaded from the disk cache
     "solve.sweeps",             # fixpoint sweeps run
     "solve.active_blocks",      # family blocks active, summed over sweeps
+    "cluster.refine_iters",     # refinement solves, once per compile_graph
+    "cluster.warm_hits",        # warm starts kept, once per plan_capacity
+    "cluster.warm_attempts",    # warm starts tried, once per plan_capacity
 )
 
 _TABLE: Dict[str, list] = {}

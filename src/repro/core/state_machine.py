@@ -70,13 +70,31 @@ class ZoneInfo:
 
 
 class ZoneManager:
-    """Host-side zone bookkeeping with strict legality enforcement."""
+    """Host-side zone bookkeeping with strict legality enforcement.
+
+    Every state change goes through :meth:`_set_state`, which also keeps
+    the sets of empty, open and active zones, so the limit checks and
+    the allocator's placement scans cost no pass over all zones.
+    """
 
     def __init__(self, spec: ZNSDeviceSpec):
         self.spec = spec
         self.zones = [
             ZoneInfo(ZoneState.EMPTY, 0, False) for _ in range(spec.num_zones)
         ]
+        self._empty = set(range(spec.num_zones))
+        self._open: set = set()
+        self._active: set = set()
+
+    def _set_state(self, z: int, state: ZoneState) -> None:
+        self.zones[z].state = state
+        for members, states in ((self._empty, (ZoneState.EMPTY,)),
+                                (self._open, OPEN_STATES),
+                                (self._active, ACTIVE_STATES)):
+            if state in states:
+                members.add(z)
+            else:
+                members.discard(z)
 
     # -- queries ------------------------------------------------------------
     def state(self, z: int) -> ZoneState:
@@ -90,17 +108,28 @@ class ZoneManager:
 
     @property
     def open_count(self) -> int:
-        return sum(1 for zi in self.zones if zi.state in OPEN_STATES)
+        return len(self._open)
 
     @property
     def active_count(self) -> int:
-        return sum(1 for zi in self.zones if zi.state in ACTIVE_STATES)
+        return len(self._active)
+
+    # The sets themselves, for readers that must not pay a copy; read
+    # them, never change them.
+    @property
+    def empty_set(self) -> set:
+        return self._empty
+
+    @property
+    def open_set(self) -> set:
+        return self._open
+
+    @property
+    def active_set(self) -> set:
+        return self._active
 
     def find_empty(self) -> Optional[int]:
-        for z, zi in enumerate(self.zones):
-            if zi.state == ZoneState.EMPTY:
-                return z
-        return None
+        return min(self._empty) if self._empty else None
 
     # -- transitions ----------------------------------------------------------
     def _check_limits(self, z: int) -> None:
@@ -123,7 +152,7 @@ class ZoneManager:
         zi = self.zones[z]
         if zi.state not in OPEN_STATES:
             raise ZoneError(f"close on zone {z} in state {zi.state.name}")
-        zi.state = ZoneState.CLOSED
+        self._set_state(z, ZoneState.CLOSED)
 
     def finish(self, z: int) -> float:
         """Finish a zone; returns the occupancy at finish time (for costing)."""
@@ -133,7 +162,7 @@ class ZoneManager:
         if zi.state == ZoneState.FULL:
             raise ZoneError("finish on FULL zone is not permitted (§III-E)")
         occ = self.occupancy(z)
-        zi.state = ZoneState.FULL
+        self._set_state(z, ZoneState.FULL)
         zi.was_finished = True
         zi.write_pointer = self.spec.zone_cap_bytes
         return occ
@@ -145,7 +174,7 @@ class ZoneManager:
             raise ZoneError(f"reset on zone {z} in state {zi.state.name}")
         occ = self.occupancy(z)
         finished = zi.was_finished
-        zi.state = ZoneState.EMPTY
+        self._set_state(z, ZoneState.EMPTY)
         zi.write_pointer = 0
         zi.was_finished = False
         return occ, finished
@@ -180,10 +209,10 @@ class ZoneManager:
             )
         self._check_limits(z)
         lba = self.spec.zone_start(z) + zi.write_pointer
-        zi.state = _TRANSITIONS[(zi.state, op)]
         zi.write_pointer += nbytes
-        if zi.write_pointer == self.spec.zone_cap_bytes:
-            zi.state = ZoneState.FULL
+        self._set_state(z, ZoneState.FULL
+                        if zi.write_pointer == self.spec.zone_cap_bytes
+                        else _TRANSITIONS[(zi.state, op)])
         return lba
 
     def read(self, z: int, offset: int = 0, nbytes: int = 1) -> None:
@@ -215,7 +244,7 @@ class ZoneManager:
             raise ZoneError(f"{op.name} on zone {z} in state {zi.state.name}")
         if op == OpType.OPEN:
             self._check_limits(z)
-        zi.state = _TRANSITIONS[key]
+        self._set_state(z, _TRANSITIONS[key])
 
 
 # ---------------------------------------------------------------------------

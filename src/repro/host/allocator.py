@@ -30,7 +30,7 @@ import dataclasses
 from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.core import MiB, ZNSDeviceSpec, ZoneError, ZoneManager, ZoneState
-from repro.core.spec import ACTIVE_STATES, OPEN_STATES
+from repro.core.spec import OPEN_STATES
 
 from repro.core.registry import Registry
 
@@ -58,13 +58,22 @@ class StreamHint:
 
 class _PlanView:
     """Shadow of zone states during one ``plan()`` — placement decisions
-    must not mutate the device before ``commit``."""
+    must not mutate the device before ``commit``.
+
+    The zone manager keeps its empty, open and active zones as sets; the
+    view derives its own from those and the few zones this plan touched
+    (newly opened, or filled to capacity), so counting and listing
+    candidates never scan every zone.  A zone the manager holds open or
+    closed is below capacity (a write that fills it, and ``finish``,
+    make it FULL), so only a zone this plan wrote can be filled here.
+    """
 
     def __init__(self, alloc: "ZoneAllocator"):
         self.alloc = alloc
         self.spec = alloc.spec
         self._wp: Dict[int, int] = {}
         self._opened: set = set()     # zones this plan newly opens
+        self._filled: set = set()     # zones this plan fills to capacity
 
     def wp(self, z: int) -> int:
         return self._wp.get(z, self.alloc.zm.write_pointer(z))
@@ -82,15 +91,19 @@ class _PlanView:
     def remaining(self, z: int) -> int:
         return self.spec.zone_cap_bytes - self.wp(z)
 
+    def _open(self) -> set:
+        return (self.alloc.zm.open_set | self._opened) - self._filled
+
+    def _active(self) -> set:
+        return (self.alloc.zm.active_set | self._opened) - self._filled
+
     @property
     def open_count(self) -> int:
-        return sum(1 for z in range(self.spec.num_zones)
-                   if self.state(z) in OPEN_STATES)
+        return len(self._open())
 
     @property
     def active_count(self) -> int:
-        return sum(1 for z in range(self.spec.num_zones)
-                   if self.state(z) in ACTIVE_STATES)
+        return len(self._active())
 
     def can_open_new(self) -> bool:
         return (self.open_count < self.spec.max_open_zones
@@ -98,25 +111,20 @@ class _PlanView:
 
     def open_zones(self) -> List[int]:
         """Writable non-reserved zones this plan may target without a
-        limit violation: open zones with capacity always qualify; CLOSED
-        zones re-open on write, so they qualify only while the open
-        count has headroom."""
+        limit violation, lowest first: open zones with capacity always
+        qualify; CLOSED zones re-open on write, so they qualify only
+        while the open count has headroom."""
         skip = self.alloc.reserved | self.alloc.frozen
-        out = []
-        open_headroom = self.open_count < self.spec.max_open_zones
-        for z in range(self.spec.num_zones):
-            if z in skip or self.remaining(z) <= 0:
-                continue
-            st = self.state(z)
-            if st in OPEN_STATES or (st == ZoneState.CLOSED
-                                     and open_headroom):
-                out.append(z)
-        return out
+        opened = self._open()
+        # the active zones are the open ones and the CLOSED ones
+        cands = self._active() if len(opened) < self.spec.max_open_zones \
+            else opened
+        return sorted(z for z in cands
+                      if z not in skip and self.remaining(z) > 0)
 
     def empty_zones(self) -> List[int]:
         skip = self.alloc.reserved | self.alloc.frozen
-        return [z for z in range(self.spec.num_zones)
-                if z not in skip and self.state(z) == ZoneState.EMPTY]
+        return sorted(self.alloc.zm.empty_set - self._opened - skip)
 
     def place(self, z: int, nbytes: int) -> Extent:
         if self.state(z) not in OPEN_STATES:
@@ -127,6 +135,8 @@ class _PlanView:
             raise ZoneError(f"plan overflow: zone {z} has "
                             f"{self.remaining(z)} bytes, asked {nbytes}")
         self._wp[z] = wp + nbytes
+        if self._wp[z] >= self.spec.zone_cap_bytes:
+            self._filled.add(z)
         return Extent(zone=z, offset=wp, nbytes=nbytes)
 
 
